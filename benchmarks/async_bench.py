@@ -36,7 +36,6 @@ BENCH_JSON = os.path.join(ROOT, "BENCH_async_gossip.json")
 _SCRIPT = r"""
 import os, sys, json, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import repro  # jax compat shims
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
 from repro.core import (PackedParams, build_layout, build_schedule,
@@ -49,7 +48,8 @@ COMPUTE_ITERS = 50 if SMOKE else 100   # fwd/bwd+update stand-in depth
 STEPS = 8 if SMOKE else 20
 
 p = 2
-mesh = jax.make_mesh((p,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((p,), ("data",))
 sched = build_schedule(p, num_rotations=2, seed=0)
 rng = np.random.default_rng(0)
 # ~1 MiB per replica across odd-sized leaves -> a few buckets
@@ -140,6 +140,7 @@ print(json.dumps({
 def rows(smoke: bool = False):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _SCRIPT, str(int(smoke))],
                        env=env, capture_output=True, text=True, timeout=600,
                        cwd=ROOT)

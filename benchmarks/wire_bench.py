@@ -37,7 +37,6 @@ BENCH_JSON = os.path.join(ROOT, "BENCH_wire.json")
 _WIRE_SCRIPT = r"""
 import os, sys, json, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import repro  # jax compat shims
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
 from repro.core import (PackedParams, build_layout, build_schedule,
@@ -55,7 +54,8 @@ WIRES = [("fp32", 1.0), ("bf16", 1.0), ("int8", 1.0), ("fp8", 1.0),
          ("int8", 0.5)]
 
 p = 2
-mesh = jax.make_mesh((p,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((p,), ("data",))
 sched = build_schedule(p, num_rotations=2, seed=0)
 rng = np.random.default_rng(0)
 tree = {f"w{i}": jnp.asarray(rng.normal(size=(p, n)), jnp.float32)
@@ -164,6 +164,7 @@ def _drift_rows(smoke: bool):
 def rows(smoke: bool = False):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _WIRE_SCRIPT, str(int(smoke))],
                        env=env, capture_output=True, text=True, timeout=600,
                        cwd=ROOT)

@@ -1,9 +1,1 @@
-"""GossipGraD reproduction package root.
-
-Installs the jax compatibility shims (repro.compat) before any submodule
-import runs — the container may pin an older jax than the API the code
-targets.
-"""
-from . import compat as _compat
-
-_compat.install()
+"""GossipGraD reproduction package root."""

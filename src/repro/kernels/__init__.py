@@ -7,7 +7,7 @@
 #                     compressed gossip wire; decode folds into the sweeps)
 #   ssm_scan        — chunked Mamba selective scan (falcon-mamba / jamba)
 #   flash_attention — blocked causal attention w/ online softmax + windows
-from .ops import (INTERPRET, flash_mha, fused_adamw_bucket, fused_lars_bucket,
+from .ops import (interpret, flash_mha, fused_adamw_bucket, fused_lars_bucket,
                   fused_sgd_bucket, gossip_mix_bucket, gossip_mix_flat,
                   gossip_mix_tree, gossip_mix_wire_bucket, ssm_scan)
 from .quantize import (WIRE_DTYPES, WireFormat, decode_wire, dequant_flat,

@@ -429,11 +429,16 @@ def fused_adamw_ref(p, g, partner, m, v, *, lr, c1, c2, alpha=0.5, b1=0.9,
 def fused_lars_ref(p, g, partner, mom, row_scale, *, lr, alpha=0.5,
                    momentum=0.9, weight_decay=0.0):
     assert p.size % LANE == 0, p.shape
-    pf = _mix_f32(p.astype(jnp.float32), _ref_partner(partner, alpha),
+    # (rows, LANE) views with the trust scale as a (rows, 1) column, as the
+    # kernel sees them: the same broadcast gives XLA the same FMA
+    # contraction of momentum*m + g*scale, so twin and kernel agree bitwise
+    rows = lambda x: x.reshape(-1, LANE)
+    partner = _ref_partner(partner, alpha)
+    pf = _mix_f32(rows(p).astype(jnp.float32),
+                  rows(partner) if partner is not None else None,
                   alpha, p.dtype)
-    scale = jnp.repeat(row_scale.reshape(-1).astype(jnp.float32), LANE
-                       ).reshape(pf.shape)
-    np_, nm = _lars_math(pf, g.astype(jnp.float32), mom.astype(jnp.float32),
-                         scale, lr, momentum=momentum,
-                         weight_decay=weight_decay)
-    return np_.astype(p.dtype), nm
+    scale = row_scale.reshape(-1, 1).astype(jnp.float32)
+    np_, nm = _lars_math(pf, rows(g).astype(jnp.float32),
+                         rows(mom).astype(jnp.float32), scale, lr,
+                         momentum=momentum, weight_decay=weight_decay)
+    return np_.reshape(p.shape).astype(p.dtype), nm.reshape(mom.shape)

@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode — controlled by
-``repro.kernels.ops.INTERPRET`` which defaults to True unless a TPU backend
-is present. The wrappers handle padding/reshaping so arbitrary model shapes
-hit hardware-aligned kernel tiles.
+Off the TPU the kernels run in interpret mode (the CPU test path); on the TPU
+they always compile through Mosaic. ``interpret()`` decides when a kernel is
+traced, never when this module is imported. The wrappers handle
+padding/reshaping so arbitrary model shapes hit hardware-aligned kernel
+tiles.
 """
 from __future__ import annotations
 
@@ -23,20 +24,17 @@ from .ssm_scan import ssm_scan_chunked
 
 PyTree = Any
 
-__all__ = ["INTERPRET", "gossip_mix_flat", "gossip_mix_tree",
+__all__ = ["interpret", "gossip_mix_flat", "gossip_mix_tree",
            "gossip_mix_bucket", "gossip_mix_wire_bucket", "fused_sgd_bucket",
            "fused_adamw_bucket", "fused_lars_bucket", "ssm_scan",
            "flash_mha"]
 
 
-def _default_interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
-
-
-INTERPRET = _default_interpret()
+def interpret() -> bool:
+    """True unless the default backend is a TPU. A backend that fails to
+    start raises here rather than quietly turning kernels into their
+    interpreter."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("alpha",))
@@ -48,7 +46,7 @@ def gossip_mix_flat(a: jnp.ndarray, b: jnp.ndarray,
     through the kernel, < LANE tail in a jnp epilogue) — no full-buffer pad
     copy."""
     return gossip_mix_1d(a.reshape(-1), b.reshape(-1), alpha=alpha,
-                         interpret=INTERPRET).reshape(a.shape)
+                         interpret=interpret()).reshape(a.shape)
 
 
 def gossip_mix_tree(a: PyTree, b: PyTree, alpha: float = 0.5) -> PyTree:
@@ -68,8 +66,9 @@ def gossip_mix_bucket(a: jnp.ndarray, b: jnp.ndarray,
     """
     n = int(np.prod(a.shape))
     assert n % LANE == 0, f"bucket size {a.shape} not LANE-aligned"
+    itp = interpret()
     out = gossip_mix_2d(a.reshape(-1, LANE), b.reshape(-1, LANE), alpha=alpha,
-                        interpret=INTERPRET, donate=not INTERPRET)
+                        interpret=itp, donate=not itp)
     return out.reshape(a.shape)
 
 
@@ -85,10 +84,11 @@ def gossip_mix_wire_bucket(a: jnp.ndarray, payload, alpha=0.5) -> jnp.ndarray:
         return gossip_mix_bucket(a, payload, alpha=alpha)
     n = int(np.prod(a.shape))
     assert n % LANE == 0, f"bucket size {a.shape} not LANE-aligned"
+    itp = interpret()
     out = gossip_mix_q2d(a.reshape(-1, LANE),
                          payload["q"].reshape(-1, LANE),
                          payload["s"].reshape(-1), alpha=alpha,
-                         interpret=INTERPRET, donate=not INTERPRET)
+                         interpret=itp, donate=not itp)
     return out.reshape(a.shape)
 
 
@@ -102,7 +102,7 @@ def _fused_impl(impl: Optional[str]) -> str:
     the twin.
     """
     if impl is None:
-        return "jnp" if INTERPRET else "pallas"
+        return "jnp" if interpret() else "pallas"
     if impl not in ("pallas", "jnp"):
         raise ValueError(f"unknown fused-update impl {impl!r}")
     return impl
@@ -130,7 +130,7 @@ def fused_sgd_bucket(p, g, partner, mom, *, lr, alpha=0.5, momentum=0.9,
     return fused_sgd_1d(p, g, partner, mom, lr=lr, alpha=alpha,
                         momentum=momentum, weight_decay=weight_decay,
                         partner_scales=scales,
-                        interpret=INTERPRET, donate=not INTERPRET)
+                        interpret=interpret(), donate=not interpret())
 
 
 def fused_adamw_bucket(p, g, partner, m, v, *, lr, c1, c2, alpha=0.5, b1=0.9,
@@ -151,7 +151,7 @@ def fused_adamw_bucket(p, g, partner, m, v, *, lr, c1, c2, alpha=0.5, b1=0.9,
     return fused_adamw_1d(p, g, partner, m, v, lr=lr, c1=c1, c2=c2,
                           alpha=alpha, b1=b1, b2=b2, eps=eps,
                           weight_decay=weight_decay, partner_scales=scales,
-                          interpret=INTERPRET, donate=not INTERPRET)
+                          interpret=interpret(), donate=not interpret())
 
 
 def fused_lars_bucket(p, g, partner, mom, row_scale, *, lr, alpha=0.5,
@@ -165,7 +165,7 @@ def fused_lars_bucket(p, g, partner, mom, row_scale, *, lr, alpha=0.5,
                               weight_decay=weight_decay)
     return fused_lars_1d(p, g, partner, mom, row_scale, lr=lr, alpha=alpha,
                          momentum=momentum, weight_decay=weight_decay,
-                         interpret=INTERPRET, donate=not INTERPRET)
+                         interpret=interpret(), donate=not interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d"))
@@ -183,7 +183,7 @@ def ssm_scan(dA: jnp.ndarray, dBx: jnp.ndarray, chunk: int = 128,
         padw = ((0, 0), (0, Sp - S), (0, Dp - D), (0, 0))
         dA = jnp.pad(dA, padw)
         dBx = jnp.pad(dBx, padw)
-    h = ssm_scan_chunked(dA, dBx, chunk=ch, block_d=bd, interpret=INTERPRET)
+    h = ssm_scan_chunked(dA, dBx, chunk=ch, block_d=bd, interpret=interpret())
     if padded:
         h = h[:, :S, :D]
     return h
@@ -193,4 +193,4 @@ def flash_mha(q, k, v, *, causal=True, window=None, block_q=128, block_k=128):
     """(B,H,S,d) x (B,H,T,d) flash attention (full heads)."""
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
-                           interpret=INTERPRET)
+                           interpret=interpret())

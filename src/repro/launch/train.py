@@ -1,28 +1,32 @@
 """Training launcher CLI.
 
-On real hardware this drives the production mesh; on this container it runs
-reduced configs on the single CPU device (--smoke, default when only one
-device is present). The gossip phase cycles through the schedule with one
-compiled step per phase (static mode).
+Trains the named config at its published widths on the devices present:
+under ``dist_mode="replica"`` every device holds one model replica (one
+chip: data=1; a four-chip host: data=4). ``--smoke`` is the only thing that
+shrinks a config (to ``--d-model`` in fp32, remat off, on the ``--smoke-mesh``
+of possibly forced-host CPU devices). The gossip phase cycles through the
+schedule with one compiled step per phase (static mode).
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch qwen3-0.6b --protocol gossip --steps 50 --smoke
+
+The last line printed is a JSON summary naming the device it ran on.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
-import os
+from typing import Dict, List, Optional
 
 import jax
-import numpy as np
 
 from repro.checkpoint import (checkpoint_exists, read_manifest, restore_state,
                               save_state)
 from repro.configs import get_config, list_archs
 from repro.data import ShardedTokenDataset
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.cache import setup_compile_cache
+from repro.launch.mesh import make_device_mesh, make_smoke_mesh
 from repro.launch.specs import train_input_specs
 from repro.models import reduced
 from repro.optim import scale_lr_sqrt_p, sgd, step_decay
@@ -30,8 +34,10 @@ from repro.train import (Trainer, init_train_state, make_distribution,
                          make_train_step_bundle)
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", default="qwen3-0.6b", choices=list_archs())
     ap.add_argument("--protocol", default="gossip",
                     choices=["gossip", "gossip_async", "agd", "every_logp",
@@ -81,14 +87,14 @@ def main() -> None:
                     "runs, --no-fused-update restores the mix-then-apply "
                     "composition)")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config on the local device mesh")
+                    help="reduced config (--d-model, fp32, no remat) on the "
+                    "--smoke-mesh")
     ap.add_argument("--smoke-mesh", default="1,1,1", metavar="POD,DATA,MODEL",
                     help="smoke-mesh axis sizes; pod>1 or data/model>1 need "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=N set "
                     "before launch. With an fsdp-mode arch this exercises "
                     "the hierarchical shard-local packed engine on CPU "
                     "(gossip over pod, FSDP+TP over data/model)")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--resume", action="store_true",
@@ -98,17 +104,22 @@ def main() -> None:
                     "checkpoint written at another --staleness is "
                     "mask-padded / truncated into this run's ring)")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
 
+
+def build_trainer(args: argparse.Namespace) -> tuple[Trainer, int]:
+    """(trainer, start_step) for parsed launcher ``args``: config, mesh,
+    step bundle, state placed with the step's shardings (restored from
+    ``--checkpoint`` under ``--resume``) and the sharded token pipeline."""
     cfg = get_config(args.arch)
-    if args.smoke or len(jax.devices()) == 1:
+    if args.smoke:
         cfg = dataclasses.replace(
             reduced(cfg, d_model=args.d_model),
             param_dtype="float32", compute_dtype="float32")
         pod, data, model = (int(x) for x in args.smoke_mesh.split(","))
         mesh = make_smoke_mesh(data, model, pod=pod)
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_device_mesh()
     dist = make_distribution(mesh, cfg.dist_mode)
 
     lr = step_decay(args.lr, 0.1, max(args.steps // 3, 1))
@@ -128,11 +139,14 @@ def main() -> None:
         wire_dtype=args.wire_dtype, gossip_subset=args.gossip_subset,
         wire_seed=args.wire_seed,
         fused_update=args.fused_update,
-        remat=not (args.smoke or len(jax.devices()) == 1))
-    state, _ = init_train_state(jax.random.key(0), cfg, dist, opt,
-                                packed=args.packed, layout=bundle.layout,
-                                inbox=bundle.protocol.staleness,
-                                wire=bundle.wire)
+        remat=not args.smoke)
+    # built under jit with the step's shardings, so each replica's state is
+    # created on its own devices and never staged whole on device 0
+    state = jax.jit(
+        lambda key: init_train_state(
+            key, cfg, dist, opt, packed=args.packed, layout=bundle.layout,
+            inbox=bundle.protocol.staleness, wire=bundle.wire)[0],
+        out_shardings=bundle.state_shardings)(jax.random.key(0))
 
     start_step = 0
     if args.resume and args.checkpoint and checkpoint_exists(args.checkpoint):
@@ -149,16 +163,26 @@ def main() -> None:
     ds = ShardedTokenDataset(cfg.vocab, args.seq_len,
                              n_shards=max(dist.dp, 1),
                              batch_per_shard=args.global_batch // max(dist.dp, 1))
-    trainer = Trainer(bundle, state, ds, log_every=args.log_every)
+    return Trainer(bundle, state, ds, log_every=args.log_every), start_step
+
+
+def device_info() -> Dict:
+    """The device the run used, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def run(argv: Optional[List[str]] = None) -> Dict:
+    """Parse ``argv``, train, save ``--checkpoint``; return the summary."""
+    args = build_parser().parse_args(argv)
+    trainer, start_step = build_trainer(args)
+    bundle = trainer.bundle
     hist = trainer.run(args.steps, start_step=start_step)
-    print(json.dumps({"arch": cfg.name, "protocol": args.protocol,
-                      "final_loss": hist[-1]["loss"],
-                      "first_loss": hist[0]["loss"],
-                      "start_step": start_step}))
     if args.checkpoint:
         end_step = start_step + args.steps
         save_state(args.checkpoint, trainer.state,
-                   metadata={"arch": cfg.name, "protocol": args.protocol,
+                   metadata={"arch": bundle.cfg.name, "protocol": args.protocol,
                              "staleness": bundle.protocol.staleness,
                              "drop_timeout": args.drop_timeout,
                              "wire_dtype": args.wire_dtype,
@@ -167,6 +191,14 @@ def main() -> None:
                              "phase": end_step % max(bundle.protocol.period, 1)},
                    step=end_step)
         print(f"checkpoint -> {args.checkpoint}")
+    return {"arch": bundle.cfg.name, "protocol": args.protocol,
+            "final_loss": hist[-1]["loss"], "first_loss": hist[0]["loss"],
+            "start_step": start_step, "device": device_info()}
+
+
+def main() -> None:
+    setup_compile_cache()
+    print(json.dumps(run()))
 
 
 if __name__ == "__main__":

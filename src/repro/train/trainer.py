@@ -30,8 +30,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.data import ShardedTokenDataset, make_replica_batches
 from .step import TrainStepBundle
@@ -62,7 +60,8 @@ class Trainer:
         self._inflight: collections.deque = collections.deque()
         self.history: List[Dict[str, float]] = []
 
-    def _step_fn(self, phase: int):
+    def step_fn(self, phase: int):
+        """The jitted step for schedule ``phase`` (folded by the period)."""
         period = max(self.bundle.protocol.period, 1)
         phase = phase % period
         if phase not in self._steps_cache:
@@ -92,14 +91,19 @@ class Trainer:
             if hasattr(oldest, "block_until_ready"):
                 oldest.block_until_ready()
 
+    def batch(self, step: int):
+        """Step ``step``'s (dp, local_b, ...) batch, each replica's slice
+        placed straight on its own devices."""
+        host = make_replica_batches(self.dataset, step,
+                                    max(self.bundle.dist.dp, 1))
+        return jax.device_put(host, self.bundle.batch_shardings)
+
     def run(self, num_steps: int, start_step: int = 0) -> List[Dict[str, float]]:
-        dp = max(self.bundle.dist.dp, 1)
-        batch = jax.tree.map(
-            jnp.asarray, make_replica_batches(self.dataset, start_step, dp))
+        batch = self.batch(start_step)
         t0 = time.perf_counter()
         pending: List = []  # (step, device-side metrics) not yet transferred
         for step in range(start_step, start_step + num_steps):
-            fn = self._step_fn(step)
+            fn = self.step_fn(step)
             self.state, rotated, metrics = fn(self.state, batch)
             pending.append((step, metrics))
             self._bound_inflight(metrics)
@@ -112,7 +116,6 @@ class Trainer:
             # fresh data each step; the device-side rotation is exercised in
             # the step itself, the pipeline applies the equivalent host-side
             # shard rotation for the *next* step's content.
-            batch = jax.tree.map(
-                jnp.asarray, make_replica_batches(self.dataset, step + 1, dp))
+            batch = self.batch(step + 1)
         self._drain(pending)
         return self.history

@@ -12,10 +12,6 @@ SRC = os.path.join(ROOT, "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
-# install the jax compat shims (repro/compat.py) before any test module does
-# `from jax.sharding import AxisType` on an older jax
-import repro  # noqa: E402,F401
-
 
 def pytest_configure(config):
     config.addinivalue_line(

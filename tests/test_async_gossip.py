@@ -310,6 +310,7 @@ def test_trainer_inflight_window_bounds():
                                         {"loss": jnp.float32(0.0)})
         bundle = types.SimpleNamespace(
             protocol=proto, dist=_Dist(), layout=None,
+            batch_shardings=None,   # batches on the default device
             jitted=lambda phase, donate=True: step_fn)
         ds = ShardedTokenDataset(vocab=32, seq_len=8, n_shards=1,
                                  batch_per_shard=1, seed=0)
@@ -446,7 +447,6 @@ def test_legacy_inbox_checkpoint_restores_as_ring(tmp_path):
 _EQUIV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro  # jax compat shims
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import (build_schedule, build_layout, PackedParams,
@@ -455,7 +455,8 @@ from repro.core import (build_schedule, build_layout, PackedParams,
                         gossip_mix_sim_delayed_k)
 from repro.kernels import gossip_mix_bucket
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 p = 8
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -558,6 +559,7 @@ def test_async_shardmap_matches_delayed_k_oracle():
     with zero drops reproduces the PR-2 staleness-1 oracle exactly."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _EQUIV_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -567,7 +569,6 @@ def test_async_shardmap_matches_delayed_k_oracle():
 _E2E_SCRIPT = r"""
 import os, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.checkpoint import restore_state, save_state
@@ -658,6 +659,7 @@ def test_async_train_checkpoint_resume_p8():
     persist); a k=1 checkpoint boots a k=4 run by mask-padding."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _E2E_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr

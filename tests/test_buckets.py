@@ -269,7 +269,8 @@ from repro.core import (build_schedule, make_gossip_mix,
                         build_layout, PackedParams)
 from repro.kernels import gossip_mix_bucket
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 p = 8
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -318,6 +319,7 @@ print("ALL_OK")
 def test_bucketed_equals_leaf_all_phases():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _EQUIV_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr

@@ -42,6 +42,7 @@ print("PROTO_OK")
 def test_dryrun_run_one_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]

@@ -416,7 +416,6 @@ def test_fused_requires_packed_and_backend():
 _ENGINE_SCRIPT = r"""
 import os, functools
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import (build_schedule, build_layout, PackedParams,
                         exchange_ok, init_inbox_ring,
@@ -424,7 +423,8 @@ from repro.core import (build_schedule, build_layout, PackedParams,
                         make_packed_fused_async_update)
 from repro.optim import sgd, adamw
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 p = 8
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -624,6 +624,7 @@ def test_fused_engine_matches_unfused_p8():
     and (b) a doubly-stochastic mean-preservation invariant at lr=0."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _ENGINE_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr

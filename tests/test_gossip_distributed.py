@@ -12,13 +12,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro  # jax compat shims (AxisType / shard_map on older jax)
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import (build_schedule, make_gossip_mix, gossip_mix_sim,
                         make_ring_shuffle)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 p = 4
 sched = build_schedule(p, num_rotations=2, seed=3)
 rng = np.random.default_rng(0)
@@ -61,6 +61,7 @@ print("ALL_OK")
 def test_shardmap_gossip_matches_simulator():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -70,13 +71,13 @@ def test_shardmap_gossip_matches_simulator():
 _KERNEL_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro  # jax compat shims (AxisType / shard_map on older jax)
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import build_schedule, make_gossip_mix, gossip_mix_sim
 from repro.kernels import gossip_mix_tree
 
-mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 p = 4
 sched = build_schedule(p, num_rotations=2, seed=5)
 rng = np.random.default_rng(1)
@@ -103,6 +104,7 @@ def test_gossip_with_pallas_mix_kernel():
     mix_impl and matches the simulator."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _KERNEL_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr

@@ -251,7 +251,8 @@ from repro.core import (build_schedule, make_gossip_mix,
 from repro.kernels import gossip_mix_bucket
 from repro.optim import sgd
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 p = 2
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -440,6 +441,7 @@ print("ALL_OK")
 def _run_sub(script: str) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr

@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import flash_mha, gossip_mix_flat, ssm_scan
 from repro.kernels.ref import attention_ref, gossip_mix_ref, ssm_scan_ref

@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.config import SSMSpec
 from repro.models.mamba import (mamba_apply, mamba_decode, mamba_init,

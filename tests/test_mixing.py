@@ -1,9 +1,6 @@
 """Mixing-matrix theory (GossipGraD §6) made executable."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (build_schedule, consensus_contraction,
                         is_doubly_stochastic, mixing_matrix, round_matrix,

@@ -4,10 +4,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.config import MoESpec
 from repro.models.layers import silu

@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import restore_state, save_state
 from repro.core import RingShardRotation

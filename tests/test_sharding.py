@@ -1,11 +1,11 @@
 """Logical-axis -> PartitionSpec rules (no multi-device needed: meshes over
 1 device still validate spec construction logic via abstract axis sizes is
 not possible, so we build tiny meshes and check rule outcomes)."""
-import jax
 import numpy as np
 import pytest
-from jax.sharding import AxisType, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_smoke_mesh
 from repro.train.sharding import Distribution
 
 
@@ -13,8 +13,7 @@ def _mesh1():
     # single real device: mesh (1,1) exercises rule selection; axis sizes of
     # 1 make every divisibility test pass trivially, so for divisibility we
     # fake sizes via a spec-level unit test below.
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    return make_smoke_mesh(1, 1)
 
 
 def test_replica_mode_rules():

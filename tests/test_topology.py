@@ -3,10 +3,7 @@ import math
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: fixed-grid fallback
-    from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (build_schedule, diffusion_steps, dissemination_partner,
                         hypercube_partner, log2_steps, reachability,

@@ -1,0 +1,111 @@
+"""Compile the main path's kernels for a described TPU v5e, no chip needed.
+
+Interpret mode never checks Mosaic's tiling rules or VMEM budget; these
+compiles do, at the real bucket size (``DEFAULT_BUCKET_BYTES``), and assert
+that each kernel lowered to a Mosaic ``tpu_custom_call``. Nothing runs, so
+they say nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.buckets import DEFAULT_BUCKET_BYTES
+from repro.kernels.fused_update import fused_adamw_1d, fused_sgd_1d
+from repro.kernels.gossip_mix import LANE, gossip_mix_2d, gossip_mix_q2d
+from repro.kernels.quantize import encode_wire
+
+BF16_N = DEFAULT_BUCKET_BYTES // 2      # elements of one bf16 bucket
+F32_N = DEFAULT_BUCKET_BYTES // 4
+FP8 = jnp.float8_e4m3fn
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile_text(fn, args, donate=()):
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("dtype,n", [(jnp.bfloat16, BF16_N),
+                                     (jnp.float32, F32_N)])
+@pytest.mark.parametrize("alpha", [0.0, 0.5, "traced"])
+def test_fused_sgd_compiles(one_chip, dtype, n, alpha):
+    s = lambda dt: _shape(one_chip, (1, n), dt)
+    args = [s(dtype), s(dtype), s(dtype), s(dtype)]
+    if alpha == "traced":
+        args.append(_shape(one_chip, (), jnp.float32))
+        fn = lambda p, g, b, m, a: fused_sgd_1d(p, g, b, m, lr=0.1, alpha=a,
+                                                donate=True)
+    else:
+        fn = lambda p, g, b, m: fused_sgd_1d(p, g, b, m, lr=0.1, alpha=alpha,
+                                             donate=True)
+    assert "tpu_custom_call" in _compile_text(fn, args, donate=(0, 3))
+
+
+@pytest.mark.parametrize("dtype,n", [(jnp.bfloat16, BF16_N),
+                                     (jnp.float32, F32_N)])
+def test_fused_adamw_compiles(one_chip, dtype, n):
+    s = lambda dt: _shape(one_chip, (1, n), dt)
+    fn = lambda p, g, b, m, v: fused_adamw_1d(p, g, b, m, v, lr=0.1, c1=0.1,
+                                              c2=0.1, alpha=0.5, donate=True)
+    args = [s(dtype), s(dtype), s(dtype), s(jnp.float32), s(jnp.float32)]
+    assert "tpu_custom_call" in _compile_text(fn, args, donate=(0, 3, 4))
+
+
+@pytest.mark.parametrize("dtype,n", [(jnp.bfloat16, BF16_N),
+                                     (jnp.float32, F32_N)])
+def test_gossip_mix_2d_compiles(one_chip, dtype, n):
+    s = _shape(one_chip, (n // LANE, LANE), dtype)
+    fn = lambda a, b: gossip_mix_2d(a, b, alpha=0.5, donate=True)
+    assert "tpu_custom_call" in _compile_text(fn, [s, s], donate=(0,))
+
+
+@pytest.mark.parametrize("code_dtype", [jnp.int8, FP8])
+def test_gossip_mix_q2d_compiles(one_chip, code_dtype):
+    rows = BF16_N // LANE
+    args = [_shape(one_chip, (rows, LANE), jnp.bfloat16),
+            _shape(one_chip, (rows, LANE), code_dtype),
+            _shape(one_chip, (rows,), jnp.float32)]
+    fn = lambda a, q, sc: gossip_mix_q2d(a, q, sc, alpha=0.5, donate=True)
+    assert "tpu_custom_call" in _compile_text(fn, args, donate=(0,))
+
+
+@pytest.mark.parametrize("code_dtype", [jnp.int8, FP8])
+def test_fused_sgd_quantized_partner_compiles(one_chip, code_dtype):
+    s = lambda dt: _shape(one_chip, (1, BF16_N), dt)
+    args = [s(jnp.bfloat16), s(jnp.bfloat16), s(code_dtype),
+            _shape(one_chip, (BF16_N // LANE,), jnp.float32), s(jnp.bfloat16)]
+    fn = lambda p, g, q, sc, m: fused_sgd_1d(p, g, q, m, lr=0.1, alpha=0.5,
+                                             partner_scales=sc, donate=True)
+    assert "tpu_custom_call" in _compile_text(fn, args, donate=(0, 4))
+
+
+def test_fp8_wire_encode_compiles(one_chip):
+    """v5e has no fp8 hardware; the e4m3 encode must still compile."""
+    fn = lambda x: encode_wire(x, "fp8")
+    _compile_text(fn, [_shape(one_chip, (1, BF16_N), jnp.bfloat16)])

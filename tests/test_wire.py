@@ -538,7 +538,6 @@ def test_cross_wire_format_restore_resets_ring(tmp_path):
 _EQUIV_SCRIPT = r"""
 import os, functools
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro  # jax compat shims
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import (build_schedule, build_layout, PackedParams,
                         exchange_ok, init_wire_inbox_ring,
@@ -552,7 +551,8 @@ from repro.kernels.quantize import (WireFormat, decode_wire, encode_wire,
                                     wire_key, zero_payload_like)
 from repro.optim import sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 p = 8
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -763,6 +763,7 @@ def test_wired_engines_match_quantized_oracles_p8():
     reproduces the PR-5 engine exactly."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _EQUIV_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1800)
     assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
@@ -772,7 +773,6 @@ def test_wired_engines_match_quantized_oracles_p8():
 _FSDP_SCRIPT = r"""
 import os, functools
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import (build_schedule, build_layout, PackedParams,
@@ -782,7 +782,8 @@ from repro.core import (build_schedule, build_layout, PackedParams,
                         wire_period, wire_subset_of)
 from repro.kernels.quantize import WireFormat
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 p = 2
 sched = build_schedule(p, num_rotations=2, seed=11)
 rng = np.random.default_rng(2)
@@ -865,6 +866,7 @@ def test_wired_engines_fsdp_shard_local_p8():
     encodes only its stride but keys noise by global element index."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _FSDP_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1800)
     assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
@@ -874,7 +876,6 @@ def test_wired_engines_fsdp_shard_local_p8():
 _E2E_SCRIPT = r"""
 import os, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.checkpoint import restore_state, save_state
@@ -999,6 +1000,7 @@ def test_wire_train_checkpoint_resume_p8():
     on the wire)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU experiment: never reach for a chip
     r = subprocess.run([sys.executable, "-c", _E2E_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1800)
     assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
