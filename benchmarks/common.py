@@ -24,7 +24,6 @@ from repro.optim import sgd
 from repro.train import make_loss_fn
 
 # v5e constants (same as launch.roofline)
-PEAK = 197e12
 HBM = 819e9
 ICI = 50e9
 
@@ -130,23 +129,3 @@ def run_replica_lm(p: int, protocol: str, steps: int, *, seq_len=32,
     jax.block_until_ready(jax.tree.leaves(params)[0])
     wall = time.perf_counter() - t0
     return hist, wall
-
-
-def timed_us(fn, *args, iters=10, warmup=2, repeats=3) -> float:
-    """Best-of-``repeats`` mean-over-``iters`` microseconds per call.
-
-    The MIN over repeats is the standard scheduling-noise-robust estimator
-    (slowness outliers are one-sided); with the smoke suites' tiny iteration
-    counts a single mean swings 1.3-2x run to run on a busy host, which
-    would make the CI bench-regression gate flaky."""
-    for _ in range(warmup):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    best = float("inf")
-    for _ in range(max(int(repeats), 1)):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        best = min(best, (time.perf_counter() - t0) / iters * 1e6)
-    return best

@@ -10,21 +10,10 @@ suite). Figure/table mapping:
     fig12_14_accuracy  — Figs 12-14: convergence equivalence (final loss)
     fig16_loss_vs_time — Fig 16: loss after a fixed wall-time budget
     fig17_every_logp   — Fig 17: gossip vs every-log(p) all-reduce
-    kernels_bench      — Pallas kernel plumbing micro-bench
-    async_bench        — §5 async gossip: sync vs staleness-1 step time
-    fused_update_bench — fused mix+apply vs mix-then-apply update engine
-    straggler_bench    — bounded-delay runtime: step time + drift vs
-                         staleness k and drop rate (skip-on-timeout)
-    wire_bench         — compressed + partition-sampled wire: bytes/step,
-                         step time on an emulated interconnect, drift vs
-                         (wire dtype, bucket-subset fraction)
     ablation_robustness— beyond-paper: grad-vs-model gossip, dropped
                          exchanges, staleness-k convergence
-
-``--smoke`` shrinks iteration counts for CI (suites that accept it).
 """
 import argparse
-import inspect
 import sys
 import traceback
 
@@ -35,11 +24,6 @@ SUITES = [
     "fig12_14_accuracy",
     "fig16_loss_vs_time",
     "fig17_every_logp",
-    "kernels_bench",
-    "async_bench",
-    "fused_update_bench",
-    "straggler_bench",
-    "wire_bench",
     "ablation_robustness",
 ]
 
@@ -47,8 +31,6 @@ SUITES = [
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--smoke", action="store_true",
-                    help="reduced iteration counts (CI perf-trajectory run)")
     args = ap.parse_args()
     failed = []
     print("name,us_per_call,derived")
@@ -58,10 +40,7 @@ def main() -> None:
         print(f"# suite: {name}", flush=True)
         try:
             mod = __import__(f"benchmarks.{name}", fromlist=["rows"])
-            kwargs = {}
-            if args.smoke and "smoke" in inspect.signature(mod.rows).parameters:
-                kwargs["smoke"] = True
-            for row_name, us, derived in mod.rows(**kwargs):
+            for row_name, us, derived in mod.rows():
                 print(f"{row_name},{us:.2f},{derived}", flush=True)
         except Exception:
             traceback.print_exc()

@@ -503,8 +503,9 @@ def make_packed_fused_async_update(
         for i, b in enumerate(params.buckets):
             if sel_send[i]:
                 enc = _encode_bucket(layout, mesh, wire, b, t, rank, i)
-                outbox.append(jax.tree.map(
-                    lambda e: jax.lax.ppermute(e, axis_names, pairs), enc))
+                with jax.named_scope("exchange"):
+                    outbox.append(jax.tree.map(
+                        lambda e: jax.lax.ppermute(e, axis_names, pairs), enc))
             else:
                 outbox.append(zero_payload_like(b, wire.dtype))
         # each device owns exactly one replica row under the packed-engine
@@ -524,9 +525,10 @@ def make_packed_fused_async_update(
         # everything scheduled before this call (the whole fwd/bwd) plus
         # the next staleness-1 steps entirely
         slots, valid, t = ring["slots"], ring["valid"], ring["t"]
-        outbox = PackedParams(
-            [jax.lax.ppermute(b, axis_names, pairs) for b in params.buckets],
-            layout)
+        with jax.named_scope("exchange"):
+            outbox = PackedParams(
+                [jax.lax.ppermute(b, axis_names, pairs)
+                 for b in params.buckets], layout)
         # each device owns exactly one replica row under the packed-engine
         # sharding restriction, so the masked alpha is one traced scalar
         a_eff = alpha * valid[0, 0]

@@ -13,10 +13,7 @@ Asynchronicity (§5): the paper posts per-layer non-blocking sends and drives
 progress with MPI_TestAll. On TPU, XLA emits ``collective-permute-start/done``
 pairs and hoists compute between them natively, so the *structural* analogue
 is to issue one ppermute per parameter leaf ("layer-wise", the default) so the
-scheduler can overlap each with surrounding compute. (The retired
-``fused=True`` variant — concatenate all leaves into one fp32 scratch every
-step — survives only as the historical baseline inside
-``benchmarks/kernels_bench.py``.)
+scheduler can overlap each with surrounding compute.
 
 The production path is the **bucketed engine** (``make_packed_gossip_mix``):
 parameters live in a handful of persistent LANE-aligned, dtype-homogeneous
@@ -472,9 +469,10 @@ def make_packed_fused_update(
         # dispatch first: the recv depends only on the incoming params, so
         # the wire runs under everything the caller scheduled before us
         # (the whole fwd/bwd of the train step)
-        recv = PackedParams(
-            [jax.lax.ppermute(b, axis_names, pairs) for b in params.buckets],
-            layout)
+        with jax.named_scope("exchange"):
+            recv = PackedParams(
+                [jax.lax.ppermute(b, axis_names, pairs)
+                 for b in params.buckets], layout)
         return local(params, grads, opt_state, recv)
 
     def local_sync_wire(phase_idx, params, grads, opt_state):
@@ -489,8 +487,9 @@ def make_packed_fused_update(
                 alphas.append(0.0)
                 continue
             enc = _encode_bucket(layout, mesh, wire, b, phase_idx, rank, i)
-            partners.append(jax.tree.map(
-                lambda e: jax.lax.ppermute(e, axis_names, pairs), enc))
+            with jax.named_scope("exchange"):
+                partners.append(jax.tree.map(
+                    lambda e: jax.lax.ppermute(e, axis_names, pairs), enc))
             alphas.append(alpha)
         return local(params, grads, opt_state, partners, alpha_eff=alphas)
 

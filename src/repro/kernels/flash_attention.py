@@ -106,6 +106,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(B, H, S, d)

@@ -234,6 +234,7 @@ def _tiled_call(body, coefs, col_ins, lane_ins, out_dtypes, aliases, *,
         body, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((M, LANE), dt) for dt in out_dtypes],
         input_output_aliases=io_aliases, interpret=interpret,
+        name="fused_update",
     )(coef, *col_ins, *lane_ins)
     return tuple(out)
 

@@ -87,7 +87,7 @@ def gossip_mix_2d(a: jnp.ndarray, b: jnp.ndarray, alpha=0.5,
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
             input_output_aliases={0: 0} if donate else {},
-            interpret=interpret,
+            interpret=interpret, name="gossip_mix",
         )(a, b)
     al = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
@@ -97,7 +97,7 @@ def gossip_mix_2d(a: jnp.ndarray, b: jnp.ndarray, alpha=0.5,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         input_output_aliases={1: 0} if donate else {},
-        interpret=interpret,
+        interpret=interpret, name="gossip_mix",
     )(al, a, b)
 
 
@@ -146,7 +146,7 @@ def gossip_mix_q2d(a: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
             input_output_aliases={1: 0} if donate else {},
-            interpret=interpret,
+            interpret=interpret, name="gossip_mix_wire",
         )(sc, a, q)
     al = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
@@ -156,7 +156,7 @@ def gossip_mix_q2d(a: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         input_output_aliases={2: 0} if donate else {},
-        interpret=interpret,
+        interpret=interpret, name="gossip_mix_wire",
     )(al, sc, a, q)
 
 

@@ -62,5 +62,5 @@ def ssm_scan_chunked(dA: jnp.ndarray, dBx: jnp.ndarray, *,
         out_specs=io_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, D, N), dA.dtype),
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="ssm_scan",
     )(dA, dBx)

@@ -135,7 +135,8 @@ def attn_apply(p, spec: AttnSpec, x: jnp.ndarray,
     mask = None
     if spec.causal and not spec.cross:
         mask = causal_window_mask(S, T, spec.window)
-    out = _sdpa(q, k, v, mask, spec.n_kv_heads)
+    with jax.named_scope("sdpa"):
+        out = _sdpa(q, k, v, mask, spec.n_kv_heads)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -241,13 +242,15 @@ def mla_apply(p, spec: MLASpec, x: jnp.ndarray,
     v = constrain_logical(
         jnp.einsum("btl,lhk->bthk", c_kv, p["wv_b"]), "group,,heads,")
     scale = 1.0 / math.sqrt(spec.qk_nope_dim + spec.qk_rope_dim)
-    scores = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope)
-              + jnp.einsum("bshk,btk->bhst", q_rope, k_rope)).astype(jnp.float32) * scale
-    scores = constrain_logical(scores, "group,heads,,")
-    mask = causal_window_mask(S, S, spec.window)
-    scores = jnp.where(mask[None, None], scores, NEG_INF)
-    w = jax.nn.softmax(scores, -1).astype(v.dtype)
-    out = jnp.einsum("bhst,bthk->bshk", w, v)
+    with jax.named_scope("sdpa"):
+        scores = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope)
+                  + jnp.einsum("bshk,btk->bhst", q_rope, k_rope)
+                  ).astype(jnp.float32) * scale
+        scores = constrain_logical(scores, "group,heads,,")
+        mask = causal_window_mask(S, S, spec.window)
+        scores = jnp.where(mask[None, None], scores, NEG_INF)
+        w = jax.nn.softmax(scores, -1).astype(v.dtype)
+        out = jnp.einsum("bhst,bthk->bshk", w, v)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 

@@ -362,14 +362,19 @@ def make_train_step_bundle(
     # whole-loss checkpoint variant kept 130+GB of scan residuals alive.
     loss_fn = make_loss_fn(cfg, ssm_scan_impl=ssm_scan_impl, remat=remat,
                            remat_policy=remat_policy)
-    if gossip_packed:
-        # loss over the buckets: unpack is slice+reshape views fused into the
-        # forward, and its autodiff transpose packs the gradients for free
-        def replica_loss(packed_one, batch_one):
-            return loss_fn(packed_one.unpack(), batch_one)
-        grad_fn = jax.vmap(jax.value_and_grad(replica_loss, has_aux=True))
-    else:
-        grad_fn = jax.vmap(jax.value_and_grad(loss_fn, has_aux=True))
+
+    def replica_loss(params_one, batch_one):
+        # the scope names the forward; JAX names its backward
+        # (transpose(...)) and the remat recompute (rematted_computation)
+        with jax.named_scope("fwd"):
+            if gossip_packed:
+                # loss over the buckets: unpack is slice+reshape views fused
+                # into the forward, and its autodiff transpose packs the
+                # gradients for free
+                params_one = params_one.unpack()
+            return loss_fn(params_one, batch_one)
+
+    grad_fn = jax.vmap(jax.value_and_grad(replica_loss, has_aux=True))
 
     shuffle = None
     if rotate_samples and dist.dp > 1:
@@ -388,16 +393,19 @@ def make_train_step_bundle(
             # update (the engine dispatches its ppermute at the program top,
             # so the wire overlaps this fwd/bwd).
             (_, metrics), grads = grad_fn(params, batch)
-            grads = proto.comm_grads(grads, phase)
-            if proto.staleness > 0:
-                new_params, new_opt, new_inbox = fused_eng(
-                    params, grads, state["inbox"], state["opt"], phase)
-            else:
-                new_params, new_opt = fused_eng(params, grads, state["opt"],
-                                                phase)
-                if proto.name == "every_logp":
-                    # the periodic model all-reduce stays a separate
-                    # (amortized-O(1/log p)) pass
+            with jax.named_scope("exchange"):
+                grads = proto.comm_grads(grads, phase)
+            with jax.named_scope("update"):
+                if proto.staleness > 0:
+                    new_params, new_opt, new_inbox = fused_eng(
+                        params, grads, state["inbox"], state["opt"], phase)
+                else:
+                    new_params, new_opt = fused_eng(params, grads,
+                                                    state["opt"], phase)
+            if proto.staleness == 0 and proto.name == "every_logp":
+                # the periodic model all-reduce stays a separate
+                # (amortized-O(1/log p)) pass
+                with jax.named_scope("exchange"):
                     new_params = proto.comm_params(new_params, phase)
         else:
             if proto.staleness > 0:
@@ -406,18 +414,25 @@ def make_train_step_bundle(
                 # immediately. The ppermute's result is consumed only k
                 # steps later, so the wire transfer overlaps the entire
                 # forward/backward below (and the next k-1 whole steps).
-                params, new_inbox = proto.comm_params(params, phase,
-                                                      inbox=state["inbox"])
+                with jax.named_scope("exchange"):
+                    params, new_inbox = proto.comm_params(
+                        params, phase, inbox=state["inbox"])
             (_, metrics), grads = grad_fn(params, batch)
-            grads = proto.comm_grads(grads, phase)
-            new_params, new_opt = optimizer.update(params, grads,
-                                                   state["opt"])
+            with jax.named_scope("exchange"):
+                grads = proto.comm_grads(grads, phase)
+            with jax.named_scope("update"):
+                new_params, new_opt = optimizer.update(params, grads,
+                                                       state["opt"])
             if proto.staleness == 0:
-                new_params = proto.comm_params(new_params, phase)
+                with jax.named_scope("exchange"):
+                    new_params = proto.comm_params(new_params, phase)
         new_params = jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(x, dist.sharding(s)),
             new_params, param_specs)
-        next_batch = shuffle(batch) if shuffle is not None else batch
+        next_batch = batch
+        if shuffle is not None:
+            with jax.named_scope("shuffle"):
+                next_batch = shuffle(batch)
         metrics = jax.tree.map(lambda m: m.mean(), metrics)
         new_state = {"params": new_params, "opt": new_opt}
         if proto.staleness > 0:

@@ -22,6 +22,12 @@ instead of double-allocating; the caller's state object is consumed
 (``Trainer.state`` always holds the live one). Per-leaf states keep
 ``donate=False`` — their scan-stacked leaves alias model views that XLA
 cannot always reuse.
+
+Host spans: each step runs inside a ``repro.step`` span (``step_num`` and
+``phase`` as arguments) holding ``repro.dispatch`` (the jitted call),
+``repro.wait`` (blocked on the oldest in-flight step) and ``repro.input``
+(the next batch); ``repro.drain`` is the metrics' trip to the host. They are
+``jax.profiler`` annotations, written only while a profiler trace runs.
 """
 from __future__ import annotations
 
@@ -73,8 +79,11 @@ class Trainer:
         """Materialize queued device metrics into float history records.
         The only host sync in the loop — called on log boundaries and at the
         end of ``run``, never per step (a per-step ``float(v)`` blocks
-        dispatch and serializes compute with the host)."""
-        for step, metrics in pending:
+        dispatch and serializes compute with the host). One ``device_get``
+        brings every pending scalar over at once."""
+        with jax.profiler.TraceAnnotation("repro.drain"):
+            host = jax.device_get([metrics for _, metrics in pending])
+        for (step, _), metrics in zip(pending, host):
             rec = {k: float(v) for k, v in metrics.items()}
             rec["step"] = step
             self.history.append(rec)
@@ -89,33 +98,41 @@ class Trainer:
         while len(self._inflight) > self.inflight_window:
             oldest = self._inflight.popleft()
             if hasattr(oldest, "block_until_ready"):
-                oldest.block_until_ready()
+                with jax.profiler.TraceAnnotation("repro.wait"):
+                    oldest.block_until_ready()
 
     def batch(self, step: int):
         """Step ``step``'s (dp, local_b, ...) batch, each replica's slice
         placed straight on its own devices."""
-        host = make_replica_batches(self.dataset, step,
-                                    max(self.bundle.dist.dp, 1))
-        return jax.device_put(host, self.bundle.batch_shardings)
+        with jax.profiler.TraceAnnotation("repro.input"):
+            host = make_replica_batches(self.dataset, step,
+                                        max(self.bundle.dist.dp, 1))
+            return jax.device_put(host, self.bundle.batch_shardings)
 
     def run(self, num_steps: int, start_step: int = 0) -> List[Dict[str, float]]:
         batch = self.batch(start_step)
         t0 = time.perf_counter()
         pending: List = []  # (step, device-side metrics) not yet transferred
+        period = max(self.bundle.protocol.period, 1)
         for step in range(start_step, start_step + num_steps):
-            fn = self.step_fn(step)
-            self.state, rotated, metrics = fn(self.state, batch)
-            pending.append((step, metrics))
-            self._bound_inflight(metrics)
-            if self.log_every and step % self.log_every == 0:
-                self._drain(pending)
-                rec = self.history[-1]
-                dt = time.perf_counter() - t0
-                self.log_fn(f"step {step:5d} loss {rec.get('loss', 0):.4f} "
-                            f"ce {rec.get('ce', 0):.4f} ({dt:.1f}s)")
-            # fresh data each step; the device-side rotation is exercised in
-            # the step itself, the pipeline applies the equivalent host-side
-            # shard rotation for the *next* step's content.
-            batch = self.batch(step + 1)
+            with jax.profiler.StepTraceAnnotation(
+                    "repro.step", step_num=step, phase=step % period):
+                fn = self.step_fn(step)
+                with jax.profiler.TraceAnnotation("repro.dispatch"):
+                    self.state, rotated, metrics = fn(self.state, batch)
+                pending.append((step, metrics))
+                self._bound_inflight(metrics)
+                if self.log_every and step % self.log_every == 0:
+                    self._drain(pending)
+                    rec = self.history[-1]
+                    dt = time.perf_counter() - t0
+                    self.log_fn(f"step {step:5d} loss "
+                                f"{rec.get('loss', 0):.4f} ce "
+                                f"{rec.get('ce', 0):.4f} ({dt:.1f}s)")
+                # fresh data each step; the device-side rotation is
+                # exercised in the step itself, the pipeline applies the
+                # equivalent host-side shard rotation for the *next* step's
+                # content.
+                batch = self.batch(step + 1)
         self._drain(pending)
         return self.history

@@ -2,8 +2,7 @@
 PackedParams-as-pytree behavior, checkpoint format stability, packed-vs-leaf
 training equivalence, and (subprocess, 8 forced host devices) mix equivalence
 bucketed == per-leaf == simulator across every schedule phase of p=8 for
-bf16 and fp32 with odd leaf sizes.  (The retired ``fused=True`` concat path
-lives on only as the historical baseline in benchmarks/kernels_bench.py.)"""
+bf16 and fp32 with odd leaf sizes."""
 import dataclasses
 import os
 import subprocess

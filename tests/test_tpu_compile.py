@@ -9,7 +9,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import importlib.util
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +51,16 @@ def _compile_text(fn, args, donate=()):
 
 def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _update_kernel_pattern():
+    """The benchmark's update reader's pattern for the fused kernel's ops."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench" / "metrics"
+            / "update_roofline.py")
+    spec = importlib.util.spec_from_file_location("update_roofline", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNEL
 
 
 @pytest.mark.parametrize("dtype,n", [(jnp.bfloat16, BF16_N),
@@ -109,3 +121,29 @@ def test_fp8_wire_encode_compiles(one_chip):
     """v5e has no fp8 hardware; the e4m3 encode must still compile."""
     fn = lambda x: encode_wire(x, "fp8")
     _compile_text(fn, [_shape(one_chip, (1, BF16_N), jnp.bfloat16)])
+
+
+def test_kernels_carry_their_names(one_chip):
+    """Each Mosaic call is named after its kernel, so a trace finds the
+    fused update by name: the update reader's pattern matches its calls and
+    not the gossip mixes'."""
+    kernel = _update_kernel_pattern()
+    n = 8 * LANE + 5                      # an aligned body and a ragged tail
+    s = lambda dt: _shape(one_chip, (1, n), dt)
+    fused = _compile_text(
+        lambda p, g, b, m: fused_sgd_1d(p, g, b, m, lr=0.1, alpha=0.5),
+        [s(jnp.bfloat16)] * 4)
+    m = _shape(one_chip, (8, LANE), jnp.bfloat16)
+    mix = _compile_text(lambda a, b: gossip_mix_2d(a, b, alpha=0.5), [m, m])
+    q = [m, _shape(one_chip, (8, LANE), jnp.int8),
+         _shape(one_chip, (8,), jnp.float32)]
+    wire = _compile_text(
+        lambda a, c, sc: gossip_mix_q2d(a, c, sc, alpha=0.5), q)
+    calls = lambda text: [line.strip().removeprefix("ROOT ")
+                          for line in text.splitlines()
+                          if "custom-call(" in line]
+    assert calls(fused) and all(c.startswith("%fused_update")
+                                and kernel.match(c) for c in calls(fused))
+    for text, name in ((mix, "%gossip_mix."), (wire, "%gossip_mix_wire.")):
+        assert calls(text) and all(c.startswith(name) and not kernel.match(c)
+                                   for c in calls(text))
