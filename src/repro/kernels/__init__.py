@@ -6,7 +6,8 @@
 #   quantize        — int8/fp8 wire encode + per-tile-scale decode (the
 #                     compressed gossip wire; decode folds into the sweeps)
 #   ssm_scan        — chunked Mamba selective scan (falcon-mamba / jamba)
-#   flash_attention — blocked causal attention w/ online softmax + windows
+#   flash_attention — causal GQA attention, forward + dK/dV + dQ kernels
+#                     (the train path's attention on a TPU)
 from .ops import (interpret, flash_mha, fused_adamw_bucket, fused_lars_bucket,
                   fused_sgd_bucket, gossip_mix_bucket, gossip_mix_flat,
                   gossip_mix_tree, gossip_mix_wire_bucket, ssm_scan)

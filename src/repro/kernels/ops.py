@@ -189,8 +189,10 @@ def ssm_scan(dA: jnp.ndarray, dBx: jnp.ndarray, chunk: int = 128,
     return h
 
 
-def flash_mha(q, k, v, *, causal=True, window=None, block_q=128, block_k=128):
-    """(B,H,S,d) x (B,H,T,d) flash attention (full heads)."""
+def flash_mha(q, k, v, *, causal=True, window=None, block_q=None,
+              block_k=None):
+    """(B,H,S,d) x (B,K,T,d) flash attention, differentiable; blocks
+    default to the shapes' (``flash_blocks``)."""
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret())

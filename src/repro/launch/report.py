@@ -93,9 +93,14 @@ def lever(r: Dict) -> str:
     if dom == "memory":
         if is_ssm and shape == "train_4k":
             return "Pallas chunked ssm_scan kernel (VMEM-resident chunks)"
-        if shape in ("prefill_32k", "train_4k"):
-            return ("Pallas flash_attention (fuses the (S,T) score "
-                    "materialization into VMEM tiles)")
+        if shape == "prefill_32k":
+            return ("route prefill through the train path's flash_attention "
+                    "kernels, which keep the (S,T) scores in VMEM tiles")
+        if shape == "train_4k":
+            return ("flash_attention (forward, dK/dV and dQ kernels) is the "
+                    "train path's attention on a TPU replica that no mesh "
+                    "axis shards; this CPU-lowered estimate counts _sdpa's "
+                    "(S,T) scores")
         return "larger per-step batch to raise arithmetic intensity"
     return "compute-bound: near roofline; only kernel-level MXU tuning left"
 

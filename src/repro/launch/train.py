@@ -191,9 +191,15 @@ def run(argv: Optional[List[str]] = None) -> Dict:
                              "phase": end_step % max(bundle.protocol.period, 1)},
                    step=end_step)
         print(f"checkpoint -> {args.checkpoint}")
+    device = device_info()
+    paths = dict(bundle.attn_paths)
+    if device["platform"] != "tpu":
+        # the flash sites lower to the kernels on a TPU only
+        paths = {"flash": 0, "dense": sum(paths.values())}
     return {"arch": bundle.cfg.name, "protocol": args.protocol,
             "final_loss": hist[-1]["loss"], "first_loss": hist[0]["loss"],
-            "start_step": start_step, "device": device_info()}
+            "start_step": start_step, "device": device,
+            "attn_paths": paths}
 
 
 def main() -> None:

@@ -14,22 +14,28 @@ mask; decode path consumes ONE new token against a KV cache:
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.dist_ctx import constrain_logical
+from repro.dist_ctx import constrain_logical, current_distribution
+from repro.kernels.flash_attention import flash_attention, flash_blocks
 from .config import AttnSpec, MLASpec
 from .layers import Param, dense_param, norm_apply
 from .rotary import apply_rope, rope_frequencies
 
 PyTree = Any
 NEG_INF = -1e30
+_PATH_COUNTS: list = []     # open count_attn_paths() scopes, innermost last
 
 __all__ = [
     "attn_init", "attn_apply", "attn_decode", "attn_cache_init",
+    "count_attn_paths",
     "mla_init", "mla_apply", "mla_decode", "mla_cache_init", "cache_len",
 ]
 
@@ -67,22 +73,27 @@ def _rot_dim(spec: AttnSpec) -> int:
     return rd - rd % 2
 
 
-def _project_qkv(p, spec: AttnSpec, x, kv_x, q_positions, kv_positions):
-    q = constrain_logical(jnp.einsum("bsd,dhk->bshk", x, p["wq"]),
-                          "group,,heads,")
-    k = constrain_logical(jnp.einsum("btd,dhk->bthk", kv_x, p["wk"]),
-                          "group,,kv_heads,")
-    v = constrain_logical(jnp.einsum("btd,dhk->bthk", kv_x, p["wv"]),
-                          "group,,kv_heads,")
+def _project_qkv(p, spec: AttnSpec, x, kv_x, q_positions, kv_positions,
+                 heads_major: bool = False):
+    """q (B,S,H,hd) and k/v (B,T,K,hd); heads before the sequence,
+    (B,H,S,hd) and (B,K,T,hd), with ``heads_major``."""
+    out, ann = ("bhsk", "group,{},,") if heads_major else ("bshk", "group,,{},")
+    q = constrain_logical(jnp.einsum(f"bsd,dhk->{out}", x, p["wq"]),
+                          ann.format("heads"))
+    k = constrain_logical(jnp.einsum(f"bsd,dhk->{out}", kv_x, p["wk"]),
+                          ann.format("kv_heads"))
+    v = constrain_logical(jnp.einsum(f"bsd,dhk->{out}", kv_x, p["wv"]),
+                          ann.format("kv_heads"))
     if spec.qk_norm:
         q = _qk_normalize(q, p["q_norm"])
         k = _qk_normalize(k, p["k_norm"])
     rd = _rot_dim(spec)
     if rd and not spec.cross:
+        head_axis = -3 if heads_major else -2
         qc, qs = rope_frequencies(rd, q_positions, spec.rope_theta)
         kc, ks = rope_frequencies(rd, kv_positions, spec.rope_theta)
-        q = apply_rope(q, qc, qs, rd)
-        k = apply_rope(k, kc, ks, rd)
+        q = apply_rope(q, qc, qs, rd, head_axis)
+        k = apply_rope(k, kc, ks, rd, head_axis)
     return q, k, v
 
 
@@ -91,8 +102,9 @@ def _sdpa(q, k, v, mask, n_kv: int):
 
     GQA via KV repetition to the full H heads: the score/probability tensors
     then shard over the heads axis (K alone rarely divides the model axis),
-    at the cost of a 16x-sharded repeated-KV buffer — the TPU-friendly
-    trade (a Pallas flash kernel fuses all of this on real hardware)."""
+    at the cost of a 16x-sharded repeated-KV buffer. Decode, cross and
+    non-causal attention take this path everywhere, and causal
+    self-attention off the TPU (``attn_apply``)."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], n_kv
     G = H // K
@@ -121,16 +133,33 @@ def causal_window_mask(S: int, T: int, window: Optional[int],
     return m
 
 
-def attn_apply(p, spec: AttnSpec, x: jnp.ndarray,
-               memory: Optional[jnp.ndarray] = None,
-               positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Full-sequence attention. ``memory`` => cross-attention (no mask)."""
-    B, S, _ = x.shape
-    kv_x = memory if spec.cross else x
-    T = kv_x.shape[1]
-    if positions is None:
-        positions = jnp.arange(S)[None]
-    kv_positions = jnp.arange(T)[None] if spec.cross else positions
+@contextlib.contextmanager
+def count_attn_paths():
+    """Count, while tracing, the attention call sites by the path each
+    takes: ``flash`` (the Pallas kernels where the program lowers for a
+    TPU) or ``dense`` (``_sdpa``). A scanned stack of layers is one site."""
+    counts = {"flash": 0, "dense": 0}
+    _PATH_COUNTS.append(counts)
+    try:
+        yield counts
+    finally:
+        _PATH_COUNTS.pop()
+
+
+def _flash_blocks(spec: AttnSpec, S: int, T: int):
+    """The kernels' (bq, bk) where this call can take them, else None:
+    causal self-attention the blocks tile, in a replica that no mesh axis
+    shards (the heads would otherwise be split across chips)."""
+    if not spec.causal or spec.cross or S != T:
+        return None
+    dist = current_distribution()
+    if dist is not None and dist.shard_axes:
+        return None
+    return flash_blocks(S, T, spec.head_dim)
+
+
+def _dense_attn(p, spec: AttnSpec, x, kv_x, positions, kv_positions):
+    S, T = x.shape[1], kv_x.shape[1]
     q, k, v = _project_qkv(p, spec, x, kv_x, positions, kv_positions)
     mask = None
     if spec.causal and not spec.cross:
@@ -138,6 +167,52 @@ def attn_apply(p, spec: AttnSpec, x: jnp.ndarray,
     with jax.named_scope("sdpa"):
         out = _sdpa(q, k, v, mask, spec.n_kv_heads)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+
+
+def _flash_attn(p, spec: AttnSpec, x, positions, blocks, interpret=False):
+    """Causal self-attention through the flash kernels: heads-major
+    projections, q and k in v's dtype (the operands an f32 einsum's default
+    precision feeds the MXU), one kernel call per replica."""
+    q, k, v = _project_qkv(p, spec, x, x, positions, positions,
+                           heads_major=True)
+    attend = functools.partial(flash_attention, causal=True,
+                               window=spec.window, block_q=blocks[0],
+                               block_k=blocks[1], interpret=interpret)
+    dist = current_distribution()
+    if dist is not None:
+        # a Mosaic call is not partitioned by XLA: run it per device. Inside
+        # the step's replica vmap, whose spmd_axis_name shards the replica
+        # axis over the dp axes, each device holds its replica whole.
+        attend = jax.shard_map(attend, mesh=dist.mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)
+    with jax.named_scope("sdpa"):
+        out = attend(q.astype(v.dtype), k.astype(v.dtype), v)
+    return jnp.einsum("bhsk,hkd->bsd", out, p["wo"])
+
+
+def attn_apply(p, spec: AttnSpec, x: jnp.ndarray,
+               memory: Optional[jnp.ndarray] = None,
+               positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Full-sequence attention. ``memory`` => cross-attention (no mask).
+
+    Causal self-attention that the flash kernels tile lowers to them on a
+    TPU and to ``_sdpa`` elsewhere; everything else takes ``_sdpa``."""
+    S = x.shape[1]
+    kv_x = memory if spec.cross else x
+    T = kv_x.shape[1]
+    if positions is None:
+        positions = jnp.arange(S)[None]
+    kv_positions = jnp.arange(T)[None] if spec.cross else positions
+    dense = functools.partial(_dense_attn, p, spec, x, kv_x, positions,
+                              kv_positions)
+    blocks = _flash_blocks(spec, S, T)
+    if _PATH_COUNTS:
+        _PATH_COUNTS[-1]["dense" if blocks is None else "flash"] += 1
+    if blocks is None:
+        return dense()
+    return jax.lax.platform_dependent(
+        tpu=functools.partial(_flash_attn, p, spec, x, positions, blocks),
+        default=dense)
 
 
 # ------------------------------------------------------------- decode

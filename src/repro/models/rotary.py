@@ -18,19 +18,26 @@ def rope_frequencies(rot_dim: int, positions: jnp.ndarray,
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
-               rot_dim: int | None = None) -> jnp.ndarray:
-    """Rotate the first ``rot_dim`` features of x (..., S, H, head_dim);
-    cos/sin are (..., S, rot_dim/2) and broadcast over the head axis."""
+               rot_dim: int | None = None, head_axis: int = -2) -> jnp.ndarray:
+    """Rotate the interleaved pairs (x[2i], x[2i+1]) of the first
+    ``rot_dim`` features of x (..., S, H, head_dim), or of x
+    (..., H, S, head_dim) with ``head_axis=-3``; cos/sin are
+    (..., S, rot_dim/2) and broadcast over the head axis.
+
+    Written over the whole feature axis as x * cos + swap(x) * sin, with
+    swap(x)[2i] = -x[2i+1], swap(x)[2i+1] = x[2i], cos 1 and sin 0 past
+    ``rot_dim``: lane rotations and elementwise ops only, so XLA keeps the
+    projection's layout (strided pair slices lower to gathers on a TPU and
+    force relayout copies)."""
     hd = x.shape[-1]
     if rot_dim is None:
         rot_dim = hd
     if rot_dim == 0:
         return x
-    xr, xp = x[..., :rot_dim], x[..., rot_dim:]
-    x1, x2 = xr[..., ::2], xr[..., 1::2]
-    c = cos[..., None, :]  # broadcast over heads
-    s = sin[..., None, :]
-    y1 = x1 * c - x2 * s
-    y2 = x1 * s + x2 * c
-    yr = jnp.stack([y1, y2], axis=-1).reshape(xr.shape)
-    return jnp.concatenate([yr, xp], axis=-1) if rot_dim < hd else yr
+    rest = [(0, 0)] * (cos.ndim - 1) + [(0, (hd - rot_dim) // 2)]
+    c = jnp.repeat(jnp.pad(cos, rest, constant_values=1.0), 2, axis=-1)
+    s = jnp.repeat(jnp.pad(sin, rest), 2, axis=-1)
+    even = jnp.arange(hd) % 2 == 0
+    swap = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return (x * jnp.expand_dims(c, head_axis)
+            + swap * jnp.expand_dims(s, head_axis))

@@ -65,6 +65,7 @@ from repro.core.async_gossip import (inbox_ring_specs, init_inbox_ring,
 from repro.core.buckets import PackedParams, build_layout, packed_param_specs
 from repro.dist_ctx import use_distribution
 from repro.models import lm_init
+from repro.models.attention import count_attn_paths
 from repro.models.config import ModelConfig
 from repro.optim import Optimizer
 from .loss import make_loss_fn
@@ -77,7 +78,8 @@ __all__ = ["TrainStepBundle", "make_train_step_bundle", "init_train_state"]
 
 class TrainStepBundle:
     def __init__(self, *, step_fn, state_specs, batch_specs, protocol, dist,
-                 cfg, optimizer, layout=None, fused=False, wire=None):
+                 cfg, optimizer, attn_paths, layout=None, fused=False,
+                 wire=None):
         self.step_fn = step_fn          # (state, batch, *, phase:int static)
         self.state_specs = state_specs
         self.batch_specs = batch_specs
@@ -88,6 +90,9 @@ class TrainStepBundle:
         self.layout = layout            # BucketLayout when gossip_packed
         self.fused = fused              # single-sweep fused mix+apply engine
         self.wire = wire                # WireFormat when compressed/sampled
+        # attention call sites by path in the last traced step
+        # (models.attention.count_attn_paths)
+        self.attn_paths = attn_paths
 
     @property
     def state_shardings(self):
@@ -374,14 +379,19 @@ def make_train_step_bundle(
                 params_one = params_one.unpack()
             return loss_fn(params_one, batch_one)
 
-    grad_fn = jax.vmap(jax.value_and_grad(replica_loss, has_aux=True))
+    # the replica axis is sharded over the dp axes: say so to the per-replica
+    # shard_maps inside (the flash attention call), so none gathers it
+    grad_fn = jax.vmap(jax.value_and_grad(replica_loss, has_aux=True),
+                       spmd_axis_name=dist.dp_axes or None)
 
     shuffle = None
     if rotate_samples and dist.dp > 1:
         shuffle = make_ring_shuffle(mesh, dist.dp_axes, batch_specs)
 
+    attn_paths: Dict[str, int] = {}
+
     def train_step(state, batch, *, phase: int):
-      with use_distribution(dist):
+      with use_distribution(dist), count_attn_paths() as paths:
         params = state["params"]
         batch = jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(x, dist.sharding(s)),
@@ -437,12 +447,14 @@ def make_train_step_bundle(
         new_state = {"params": new_params, "opt": new_opt}
         if proto.staleness > 0:
             new_state["inbox"] = new_inbox
+        attn_paths.update(paths)
         return new_state, next_batch, metrics
 
     return TrainStepBundle(
         step_fn=train_step, state_specs=state_specs, batch_specs=batch_specs,
         protocol=proto, dist=dist, cfg=cfg, optimizer=optimizer,
-        layout=layout, fused=fused_update, wire=proto.wire)
+        layout=layout, fused=fused_update, wire=proto.wire,
+        attn_paths=attn_paths)
 
 
 def _build_packed_layout(dist: Distribution, param_shapes: PyTree,

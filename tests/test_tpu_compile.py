@@ -1,9 +1,10 @@
 """Compile the main path's kernels for a described TPU v5e, no chip needed.
 
 Interpret mode never checks Mosaic's tiling rules or VMEM budget; these
-compiles do, at the real bucket size (``DEFAULT_BUCKET_BYTES``), and assert
-that each kernel lowered to a Mosaic ``tpu_custom_call``. Nothing runs, so
-they say nothing about results or speed.
+compiles do, at the real bucket size (``DEFAULT_BUCKET_BYTES``) and the
+benchmark cell's attention shape, and assert that each kernel lowered to a
+Mosaic ``tpu_custom_call``. Nothing runs, so they say nothing about results
+or speed.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -12,16 +13,25 @@ this file.
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.buckets import DEFAULT_BUCKET_BYTES
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_update import fused_adamw_1d, fused_sgd_1d
 from repro.kernels.gossip_mix import LANE, gossip_mix_2d, gossip_mix_q2d
 from repro.kernels.quantize import encode_wire
+from repro.launch.mesh import make_mesh
+from repro.launch.specs import train_input_specs
+from repro.models.config import reduced
+from repro.optim import sgd
+from repro.train.sharding import make_distribution
+from repro.train.step import init_train_state, make_train_step_bundle
 
 BF16_N = DEFAULT_BUCKET_BYTES // 2      # elements of one bf16 bucket
 F32_N = DEFAULT_BUCKET_BYTES // 4
@@ -29,7 +39,7 @@ FP8 = jnp.float8_e4m3fn
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -41,8 +51,13 @@ def one_chip():
     # cannot be read back without one: keep the cache out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_text(fn, args, donate=()):
@@ -147,3 +162,62 @@ def test_kernels_carry_their_names(one_chip):
     for text, name in ((mix, "%gossip_mix."), (wire, "%gossip_mix_wire.")):
         assert calls(text) and all(c.startswith(name) and not kernel.match(c)
                                    for c in calls(text))
+
+
+def _custom_calls(text):
+    return [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+            if "custom-call(" in line]
+
+
+def _flash_kernels(text):
+    """The flash kernels' names among the Mosaic calls: fwd, dkv, dq."""
+    names = set()
+    for call in _custom_calls(text):
+        m = re.match(r"%\S*?flash_attention(_dkv|_dq)?[_.]", call)
+        if m:
+            names.add("flash_attention" + (m.group(1) or ""))
+    return names
+
+
+def test_flash_attention_fwd_bwd_compiles(one_chip):
+    """The train path's attention at the cell's shape (B 4, S 1024, 16
+    query and 8 KV heads of 128, bf16): forward, dK/dV and dQ lower to
+    Mosaic calls within the VMEM budget, none matching the update reader."""
+    kernel = _update_kernel_pattern()
+    q = _shape(one_chip, (4, 16, 1024, 128), jnp.bfloat16)
+    kv = _shape(one_chip, (4, 8, 1024, 128), jnp.bfloat16)
+    loss = lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v).astype(jnp.float32))
+    text = _compile_text(jax.grad(loss, (0, 1, 2)), [q, kv, kv])
+    assert _flash_kernels(text) == {"flash_attention", "flash_attention_dkv",
+                                    "flash_attention_dq"}
+    assert not any(kernel.match(c) for c in _custom_calls(text))
+
+
+def test_step_takes_flash_per_replica_on_four_chips(topo):
+    """The gossip step of a two-layer qwen3 at data=4 on a described
+    v5e:2x2: every attention site takes the kernels, and no all-gather
+    feeds them (each replica's call runs on its own chip)."""
+    cfg = reduced(get_config("qwen3-0.6b"), d_model=512)
+    mesh = make_mesh((4, 1), ("data", "model"), devices=topo.devices[:4])
+    dist = make_distribution(mesh, cfg.dist_mode)
+    opt = sgd(0.01, momentum=0.9)
+    shapes, axes, batch = train_input_specs(cfg, dist, 256, 4, opt)
+    bundle = make_train_step_bundle(
+        cfg, dist, opt, state_shapes=shapes, state_axes=axes,
+        batch_shapes=batch, protocol="gossip", gossip_packed=True,
+        remat=True)
+    state = jax.eval_shape(lambda k: init_train_state(
+        k, cfg, dist, opt, packed=True, layout=bundle.layout)[0],
+        jax.random.key(0))
+    place = lambda tree, shard: jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shard)
+    text = bundle.jitted(0).lower(
+        place(state, bundle.state_shardings),
+        place(batch, bundle.batch_shardings)).compile().as_text()
+    assert bundle.attn_paths == {"flash": 1, "dense": 0}
+    assert _flash_kernels(text) == {"flash_attention", "flash_attention_dkv",
+                                    "flash_attention_dq"}
+    assert "all-gather" not in text
+    assert "collective-permute" in text

@@ -95,3 +95,18 @@ def test_serving_engine_vlm_stub():
     img = rng.normal(size=(2, cfg.vision.n_image_tokens, cfg.d_model)).astype(np.float32) * 0.02
     out = eng.generate(prompts, max_new_tokens=3, image_embeds=img)
     assert out.shape == (2, 3)
+
+
+def test_launcher_summary_reports_attn_paths():
+    """The step counts its attention sites by path while tracing: the
+    smoke qwen3's scanned layers are one causal site the flash kernels
+    tile. The launcher's summary reports them as lowered here, off a TPU:
+    every site computes ``_sdpa``."""
+    from repro.launch import train as launcher
+    argv = ["--smoke", "--steps", "1", "--log-every", "0", "--seq-len",
+            "128", "--global-batch", "2"]
+    trainer, _ = launcher.build_trainer(launcher.build_parser().parse_args(
+        argv))
+    trainer.run(1)
+    assert trainer.bundle.attn_paths == {"flash": 1, "dense": 0}
+    assert launcher.run(argv)["attn_paths"] == {"flash": 0, "dense": 1}
