@@ -132,7 +132,7 @@ def _check_kernel_twin() -> None:
     from repro.kernels.ops import fused_sgd_bucket
 
     keys = jax.random.split(jax.random.key(1), 4)
-    p, g, b, m = (jax.random.normal(k, (1, 2048 * 128), jnp.float32)
+    p, g, b, m = (jax.random.normal(k, (1, 2048, 128), jnp.float32)
                   for k in keys)
     outs = {impl: jax.jit(lambda *a, impl=impl: fused_sgd_bucket(
         *a, lr=0.1, alpha=0.5, momentum=0.9, impl=impl))(p, g, b, m)

@@ -38,6 +38,9 @@ inbox tree, no ring keys) restore as a one-slot ring with a valid mask.
 Compressed-wire rings (core.async_gossip.init_wire_inbox_ring): int8 codes
 save natively, fp8/bf16 stage as f32 (lossless — every e4m3/bf16 value is
 exactly f32-representable) with the true dtype recorded in the manifest.
+Their payloads keep the buckets' ``(dp, rows, 128)`` storage shape; a
+checkpoint that holds them flat, ``(dp, rows * 128)``, restores by a
+reshape.
 Cross-WIRE-FORMAT restore (fp32-wire ring <-> compressed ring, either
 direction) cannot adapt slot-by-slot — the payload structures differ — so
 the params/opt subtrees restore strictly and the ring resets to the
@@ -55,7 +58,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.core.buckets import PackedParams
+from repro.core.buckets import LANE, PackedParams
 
 PyTree = Any
 
@@ -187,6 +190,20 @@ def _adapt_ring(ring: Dict, k_t: int) -> Dict:
     return {"slots": tuple(slots), "valid": valid, "t": ring["t"]}
 
 
+def _as_stored(arr: np.ndarray, shape: Tuple[int, ...], key: str
+               ) -> np.ndarray:
+    """``arr`` in the template's ``shape``. A bucket-shaped leaf (a wire
+    ring's payload codes) is stored ``(..., rows, LANE)``; checkpoints
+    written before that storage hold it flat, ``(..., rows * LANE)``, and
+    load by a reshape."""
+    if tuple(arr.shape) == shape:
+        return arr
+    if (len(shape) >= 2 and shape[-1] == LANE
+            and tuple(arr.shape) == shape[:-2] + (shape[-2] * LANE,)):
+        return arr.reshape(shape)
+    raise ValueError(f"shape mismatch at {key}: {arr.shape} vs {shape}")
+
+
 def restore_state(path: str, template: PyTree) -> Tuple[PyTree, Dict]:
     """Restore into the structure of ``template`` (shapes/dtypes validated).
     PackedParams nodes in the template are restored through their unpacked
@@ -257,10 +274,7 @@ def restore_state(path: str, template: PyTree) -> Tuple[PyTree, Dict]:
     out = []
     for pth, leaf in leaves_with_path:
         key = jax.tree_util.keystr(pth)
-        arr = arrays[key]
-        if tuple(arr.shape) != tuple(np.shape(leaf)):
-            raise ValueError(f"shape mismatch at {key}: "
-                             f"{arr.shape} vs {np.shape(leaf)}")
+        arr = _as_stored(arrays[key], tuple(np.shape(leaf)), key)
         out.append(arr.astype(leaf.dtype) if hasattr(leaf, "dtype") else arr)
     restored = jax.tree_util.tree_unflatten(treedef, out)
     if ring_adapt is not None:

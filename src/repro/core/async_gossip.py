@@ -158,7 +158,7 @@ def init_wire_inbox_ring(packed: PackedParams, staleness: int, dp: int,
     for int8/fp8; a zero bucket for fp32/bf16) instead of a params copy —
     zero payloads decode to exact zeros and the all-invalid mask means the
     first k arrival mixes consume them only at alpha = 0. Works on global
-    (dp, n) buckets (trainer init / simulator) alike."""
+    (dp, rows, 128) buckets (trainer init / simulator) alike."""
     if staleness < 1:
         raise ValueError(f"inbox ring needs staleness >= 1, got {staleness}")
     slot = tuple(zero_payload_like(b, wire.dtype) for b in packed.buckets)
@@ -173,9 +173,10 @@ def init_wire_inbox_ring(packed: PackedParams, staleness: int, dp: int,
 def wire_inbox_ring_specs(packed_specs: PackedParams, dp_axes: Sequence[str],
                           staleness: int, wire: WireFormat) -> Dict:
     """PartitionSpec tree matching ``init_wire_inbox_ring``: each slot is a
-    tuple of per-bucket payload specs — quantized payload codes AND scales
-    are flat with the bucket's sharding (strides are LANE multiples, so the
-    scale dim divides evenly across shard-local layouts)."""
+    tuple of per-bucket payload specs — quantized payload codes take the
+    bucket's sharding and their per-row scales the same without the lane
+    entry (strides are LANE multiples, so the rows divide evenly across
+    shard-local layouts)."""
     dp_axes = tuple(dp_axes)
     front = (dp_axes if len(dp_axes) > 1 else dp_axes[0]) if dp_axes else None
     slot = tuple(payload_spec(s, wire.dtype) for s in packed_specs.buckets)
@@ -318,7 +319,7 @@ def make_packed_async_gossip_mix(
     resident. Each step issues one ppermute + one (donatable, in-place,
     masked-alpha) mix per bucket; shard-local layouts (fsdp / TP inside a
     replica) are legal exactly as in the sync packed engine — the bucket
-    flat dim shards over the in-replica axes and the ppermute runs over the
+    rows shard over the in-replica axes and the ppermute runs over the
     replica axes only (``check_layout_mesh`` validates the agreement).
 
     ``wire`` (non-default): the compressed + partition-sampled wire. Ring

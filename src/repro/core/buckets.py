@@ -11,14 +11,19 @@ the tree shape entirely (``target_bucket_bytes`` is the knob) and, unlike
 both old paths, move native-dtype bytes with zero per-step packing.
 
 This module packs the parameter tree ONCE at init into a small number of
-size-balanced, LANE-aligned, dtype-homogeneous flat buckets:
+size-balanced, LANE-aligned, dtype-homogeneous buckets, each stored as the
+``(rows, 128)`` view the update and mix kernels tile over:
 
 * **dtype-homogeneous** — a bucket only holds leaves of one dtype, so the
   wire format is the native parameter dtype (bf16 buckets move half the
   bytes the old fp32 scratch did) and no per-step casts exist;
 * **LANE-aligned** — every leaf starts on a 128-element boundary and every
-  bucket length is a multiple of 128, so the Pallas mix kernel sees aligned
-  ``(rows, 128)`` tiles with no ragged tail;
+  bucket length is a multiple of 128, so a bucket is whole ``(rows, 128)``
+  rows with no ragged tail. Storing that view (``(dp, rows, 128)`` with the
+  replica axis) makes it the kernels' operand as it stands: a ``(dp, n)``
+  store would be tiled by XLA:TPU with the size-1 replica axis as sublanes
+  (half of every tile padding), and each step would relayout every
+  parameter and moment bucket into the kernels' view and back;
 * **size-balanced** — greedy bin-packing (largest leaf first onto the
   emptiest bucket) keeps buckets within ~1 max-leaf of each other, so the
   per-bucket collectives pipeline evenly against compute.
@@ -27,9 +32,10 @@ size-balanced, LANE-aligned, dtype-homogeneous flat buckets:
 the bucket buffers. Elementwise code (optimizers, replica means, sharding
 constraints) maps straight over the buckets; shape-aware code (the model
 forward, checkpointing) reads through ``.unpack()``, which is pure
-slice+reshape — XLA fuses it into consumers, and its autodiff transpose
-delivers *gradients already packed*, so the pack cost is paid exactly once
-at init instead of every step.
+slice+reshape (each leaf's whole rows, then its length) — XLA fuses it into
+consumers, and its autodiff transpose delivers *gradients already packed*,
+in the storage shape, so the pack cost is paid exactly once at init
+instead of every step.
 
 **Shard-local (hierarchical) layouts**: when the distribution shards leaves
 *inside* a replica (fsdp's FSDP+TP over the ``data``/``model`` axes, or
@@ -42,7 +48,7 @@ that the pieces form an exact PARTITION of the leaf (every element lives in
 exactly one shard's bucket bytes; nothing is duplicated, so the unpack
 transpose still delivers exact packed gradients). A bucket is then
 ``num_shards`` equal ``bucket_stride``-sized chunks laid end to end and its
-flat dim shards over the in-replica mesh axes (``packed_param_specs``), so
+rows shard over the in-replica mesh axes (``packed_param_specs``), so
 every device's local bucket block is exactly its own shard bytes — gossip
 ppermutes buckets over the replica axis only, and the mix/fused kernels
 see the same LANE-aligned ``(rows, 128)`` tiles as the flat case.
@@ -147,6 +153,11 @@ class BucketLayout:
     def hierarchical(self) -> bool:
         return self.num_shards > 1
 
+    def bucket_shape(self, b: int) -> Tuple[int, int]:
+        """Per-replica storage shape of bucket ``b``: the ``(rows, lane)``
+        view the update and mix kernels tile over."""
+        return (self.bucket_sizes[b] // self.lane, self.lane)
+
     def global_offset(self, slot: LeafSlot) -> int:
         """Element offset of ``slot`` within its bucket's full flat dim."""
         return slot.shard * self.strides[slot.bucket] + slot.offset
@@ -234,8 +245,8 @@ class BucketLayout:
                 zip(per_bucket, self.bucket_sizes, self.bucket_dtypes)):
             if cursors[b] < total:  # tail padding up to the LANE multiple
                 segs.append(jnp.zeros(lead + (total - cursors[b],), np.dtype(dt)))
-            buckets.append(segs[0] if len(segs) == 1
-                           else jnp.concatenate(segs, axis=-1))
+            flat = segs[0] if len(segs) == 1 else jnp.concatenate(segs, axis=-1)
+            buckets.append(flat.reshape(lead + self.bucket_shape(b)))
         return tuple(buckets)
 
     # ----------------------------------------------------------- unpack
@@ -253,17 +264,21 @@ class BucketLayout:
         # numpy slicing is zero-copy and np.concatenate never touches jax
         host = all(isinstance(b, np.ndarray) for b in buckets)
         cat = np.concatenate if host else jnp.concatenate
+        lane = self.lane
 
         def seg_of(slot: LeafSlot):
             b = buckets[slot.bucket]
             start = self.global_offset(slot)
-            # basic indexing: a static lax.slice under trace, a zero-copy
-            # view on host numpy buckets
-            return b[..., start:start + slot.size]
+            # the slot's whole rows, flattened, cut to its length: basic
+            # indexing, so a static lax.slice under trace (XLA fuses it into
+            # each consumer, and no whole-bucket relayout exists) and a
+            # zero-copy view on host numpy buckets
+            rows = b[..., start // lane:-(-(start + slot.size) // lane), :]
+            return rows.reshape(rows.shape[:-2] + (-1,))[..., :slot.size]
 
         leaves = []
         for group in self._slots_by_leaf():
-            lead = tuple(buckets[group[0].bucket].shape[:-1])
+            lead = tuple(buckets[group[0].bucket].shape[:-2])
             if len(group) == 1 and group[0].covers_leaf():
                 slot = group[0]
                 leaves.append(seg_of(slot).reshape(lead + slot.shape))
@@ -496,12 +511,13 @@ class PackedParams:
 
 def packed_param_specs(layout: BucketLayout,
                        dp_axes: Sequence[str]) -> PackedParams:
-    """PartitionSpec tree for packed params: every bucket is ``(dp, size)``
-    with the replica axis on the leading dim. Flat layouts leave the bucket
-    dim unsharded; shard-local layouts shard it over the layout's in-replica
-    axes — the bucket is ``num_shards`` stride-sized chunks laid end to end
-    in exactly the mesh's row-major position order, so each device's local
-    block is its own shard bytes (zero-copy legality of the hierarchical
+    """PartitionSpec tree for packed params: every bucket is ``(dp, rows,
+    LANE)`` with the replica axis on the leading dim. Flat layouts leave the
+    rows unsharded; shard-local layouts shard them over the layout's
+    in-replica axes — the bucket is ``num_shards`` stride-sized chunks laid
+    end to end in exactly the mesh's row-major position order, and every
+    stride is a LANE multiple, so each device's local block is whole rows
+    of its own shard bytes (zero-copy legality of the hierarchical
     engine)."""
     dp_axes = tuple(dp_axes)
     overlap = set(dp_axes) & set(layout.shard_axes)
@@ -515,7 +531,7 @@ def packed_param_specs(layout: BucketLayout,
         inner = sh if len(sh) > 1 else sh[0]
     else:
         inner = None
-    return PackedParams([P(front, inner)] * layout.num_buckets, layout)
+    return PackedParams([P(front, inner, None)] * layout.num_buckets, layout)
 
 
 def check_layout_mesh(layout: BucketLayout, mesh) -> None:

@@ -245,7 +245,7 @@ def make_packed_gossip_mix(
 
     Layouts sharded INSIDE a replica (fsdp / tensor parallelism) are legal
     when the layout is shard-local (built with the distribution's in-replica
-    axes — core.buckets): the bucket flat dim then shards over those axes so
+    axes — core.buckets): the bucket rows then shard over those axes so
     each device's local block is its own shard bytes, and the ppermute still
     runs over the replica axes only. ``check_layout_mesh`` validates the
     layout/mesh agreement (the shard-aware successor of the old "only
@@ -571,7 +571,7 @@ def wire_bytes_per_step(layout: BucketLayout, wire: WireFormat | None = None
     sent ``n_send``-out-of-``num_buckets`` of the time)."""
     wire = wire or WireFormat()
     subset = wire_subset_of(wire, layout.num_buckets)
-    # per-chip: each device ppermutes its own (1, stride) block per bucket
+    # per-chip: each device ppermutes its own stride-element block per bucket
     sizes = [int(s) for s in layout.strides]
     raw, code, scale = 0.0, 0.0, 0.0
     frac = 1.0 if subset is None else subset.fraction

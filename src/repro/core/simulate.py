@@ -134,8 +134,9 @@ def gossip_mix_sim_quantized(buckets, recv_from: jnp.ndarray, t, *,
     reference semantics of ``core.gossip.make_packed_gossip_mix(wire=...)``
     (and, composed with the optimizer algebra, the fused twin).
 
-    ``buckets`` is the global view: a list of ``(p, n)`` arrays, one per
-    layout bucket, each row one replica's flat LANE-multiple bucket.  One
+    ``buckets`` is the global view: a list of ``(p, rows, 128)`` arrays,
+    one per layout bucket, each entry of the leading axis one replica's
+    bucket in its storage shape.  One
     exchange at dispatch step ``t``, schedule row ``recv_from``:
 
         enc_j     = encode_wire(x_j, keyed on (t, rank=j, bucket, seed))
@@ -184,8 +185,8 @@ def gossip_mix_sim_quantized_k(buckets, ring: Any, recv_from: jnp.ndarray, *,
     semantics of ``core.async_gossip.make_packed_async_gossip_mix(wire=...)``.
 
     ``ring`` is an ``init_wire_inbox_ring`` structure over GLOBAL buckets:
-    each slot a tuple of per-bucket wire payloads (codes ``(p, n)`` +
-    scales ``(p, n//128)`` when quantized), oldest first.  One step:
+    each slot a tuple of per-bucket wire payloads (codes ``(p, rows, 128)``
+    + scales ``(p, rows)`` when quantized), oldest first.  One step:
 
         a_eff_j = alpha * valid[j, 0]
         mixed_j = (1-a_eff_j) * x_j + a_eff_j * dequant(slots[0]_j)
@@ -431,7 +432,8 @@ def make_async_sim_train_step(
         if pad:
             flat = jnp.pad(flat, ((0, 0), (0, pad)))
         keys = wire_key(t, ranks, leaf_idx, wire.seed)
-        dec = decode_wire(encode_wire(flat, wire.dtype, keys=keys))
+        dec = decode_wire(encode_wire(flat.reshape(p, -1, LANE), wire.dtype,
+                                      keys=keys)).reshape(p, -1)
         if pad:
             dec = dec[:, :n]
         return dec.reshape(m.shape).astype(m.dtype)

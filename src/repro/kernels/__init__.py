@@ -11,7 +11,7 @@
 from .ops import (interpret, flash_mha, fused_adamw_bucket, fused_lars_bucket,
                   fused_sgd_bucket, gossip_mix_bucket, gossip_mix_flat,
                   gossip_mix_tree, gossip_mix_wire_bucket, ssm_scan)
-from .quantize import (WIRE_DTYPES, WireFormat, decode_wire, dequant_flat,
+from .quantize import (WIRE_DTYPES, WireFormat, decode_wire, dequant_tiles,
                        encode_wire, payload_spec, wire_itemsize, wire_key,
                        wire_uniform, zero_payload_like)
 from . import ref

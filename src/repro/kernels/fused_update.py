@@ -89,7 +89,7 @@ def _mix_f32(p32: jnp.ndarray, partner: Optional[jnp.ndarray], alpha,
     ``partner_scale`` (quantized wire): the partner operand is int8/fp8
     CODES and ``partner_scale`` the per-(row, 128)-tile fp32 scale — the
     decode ``codes.astype(f32) * scale`` folds into this same sweep and is
-    bit-identical to the jnp oracle's ``dequant_flat`` (same op, same
+    bit-identical to the jnp oracle's ``dequant_tiles`` (same op, same
     order)."""
     if partner is None or (_alpha_static(alpha) and alpha == 0.0):
         return p32
@@ -240,8 +240,12 @@ def _tiled_call(body, coefs, col_ins, lane_ins, out_dtypes, aliases, *,
 
 
 def _split_aligned(arrs):
-    """Flatten each array; return (aligned (M, LANE) views, ragged tails)."""
+    """(aligned (M, LANE) views, ragged tails) of same-size arrays. A
+    LANE-multiple array, such as a packed bucket stored as ``(..., M,
+    LANE)``, is viewed whole: a bitcast, never a slice or a copy."""
     n = arrs[0].size
+    if n % LANE == 0:
+        return [a.reshape(-1, LANE) for a in arrs], None
     n_main = (n // LANE) * LANE
     mains = [a.reshape(-1)[:n_main].reshape(-1, LANE) for a in arrs]
     tails = [a.reshape(-1)[n_main:] for a in arrs] if n_main != n else None
@@ -261,7 +265,8 @@ def fused_sgd_1d(p, g, partner, mom, *, lr, alpha=0.5, momentum=0.9,
                  weight_decay=0.0, partner_scales=None,
                  block_rows=DEFAULT_ROWS, interpret=False,
                  donate=False) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
-    """Fused mix+SGD over a flat buffer of any length/leading shape.
+    """Fused mix+SGD over a buffer of any length/leading shape: a packed
+    bucket's ``(..., rows, 128)`` storage, or a flat buffer.
 
     The LANE-aligned prefix runs through the tiled kernel (aliasing param and
     momentum outputs onto their inputs when ``donate``); a ragged tail

@@ -104,7 +104,7 @@ def gossip_mix_2d(a: jnp.ndarray, b: jnp.ndarray, alpha=0.5,
 def _mix_kernel_q(s_ref, a_ref, q_ref, o_ref, *, alpha: float):
     # quantized-wire variant: the partner arrives as int8/fp8 codes plus one
     # fp32 scale per row, decoded in-register — codes.astype(f32) * scale is
-    # the exact op the jnp oracle (kernels.quantize.dequant_flat) runs, so
+    # the exact op the jnp oracle (kernels.quantize.dequant_tiles) runs, so
     # decode-in-kernel and decode-then-mix are bit-identical
     a = a_ref[...].astype(jnp.float32)
     b = q_ref[...].astype(jnp.float32) * s_ref[...]

@@ -19,7 +19,7 @@ from .flash_attention import flash_attention
 from .fused_update import (fused_adamw_1d, fused_adamw_ref, fused_lars_1d,
                            fused_lars_ref, fused_sgd_1d, fused_sgd_ref)
 from .gossip_mix import LANE, gossip_mix_1d, gossip_mix_2d, gossip_mix_q2d
-from .quantize import dequant_flat
+from .quantize import dequant_tiles
 from .ssm_scan import ssm_scan_chunked
 
 PyTree = Any
@@ -61,8 +61,9 @@ def gossip_mix_bucket(a: jnp.ndarray, b: jnp.ndarray,
 
     Buckets are LANE-aligned by construction (core.buckets.BucketLayout), so
     this is a single aliased kernel call — no pad, no tail, no cast: the
-    donation-friendly hot path of the packed gossip engine. Accepts any
-    leading axes (the sharded replica axis) over the flat bucket dim.
+    donation-friendly hot path of the packed gossip engine. Takes the
+    bucket's ``(..., rows, 128)`` storage as it comes (any leading axes,
+    e.g. the sharded replica axis): the kernel's view is a bitcast of it.
     """
     n = int(np.prod(a.shape))
     assert n % LANE == 0, f"bucket size {a.shape} not LANE-aligned"
@@ -79,7 +80,7 @@ def gossip_mix_wire_bucket(a: jnp.ndarray, payload, alpha=0.5) -> jnp.ndarray:
     same kernel as ``gossip_mix_bucket``) or a quantized ``{"q", "s"}`` dict
     (int8/fp8 codes + per-(row, 128)-tile fp32 scales), whose decode folds
     into the mix sweep via the scale column stream — bit-identical to
-    ``kernels.quantize.dequant_flat`` followed by the plain mix."""
+    ``kernels.quantize.dequant_tiles`` followed by the plain mix."""
     if not isinstance(payload, dict):
         return gossip_mix_bucket(a, payload, alpha=alpha)
     n = int(np.prod(a.shape))
@@ -112,16 +113,17 @@ def fused_sgd_bucket(p, g, partner, mom, *, lr, alpha=0.5, momentum=0.9,
                      weight_decay=0.0, impl: Optional[str] = None):
     """Single-sweep fused mix+SGD over one persistent gossip bucket:
     ``mixed = (1-alpha)*p + alpha*partner`` then the SGD-momentum update at
-    the mixed point, one read + one write pass, donation-friendly.  Accepts
-    any leading axes (the sharded replica axis) over the flat bucket dim and
-    ragged (non-LANE) buffers via the kernel's tail epilogue.  A quantized
+    the mixed point, one read + one write pass, donation-friendly.  Takes
+    the bucket's ``(..., rows, 128)`` storage as it comes (the kernel's view
+    is a bitcast of it) and ragged (non-LANE) flat buffers via the kernel's
+    tail epilogue.  A quantized
     wire partner (``{"q", "s"}`` dict, see kernels.quantize) is decoded
     in-kernel on the Pallas path and pre-decoded (bit-identically) on the
     jnp path."""
     scales = None
     if isinstance(partner, dict):
         if _fused_impl(impl) == "jnp":
-            partner = dequant_flat(partner["q"], partner["s"])
+            partner = dequant_tiles(partner["q"], partner["s"])
         else:
             partner, scales = partner["q"], partner["s"]
     if _fused_impl(impl) == "jnp":
@@ -141,7 +143,7 @@ def fused_adamw_bucket(p, g, partner, m, v, *, lr, c1, c2, alpha=0.5, b1=0.9,
     scales = None
     if isinstance(partner, dict):
         if _fused_impl(impl) == "jnp":
-            partner = dequant_flat(partner["q"], partner["s"])
+            partner = dequant_tiles(partner["q"], partner["s"])
         else:
             partner, scales = partner["q"], partner["s"]
     if _fused_impl(impl) == "jnp":

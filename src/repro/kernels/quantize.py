@@ -19,10 +19,11 @@ Wire payload formats (``WireFormat.dtype``):
            every scaled value <= 448, the format's max finite: e4m3fn has
            no inf, so an out-of-range cast would produce nan).
 
-A quantized payload is a dict ``{"q": codes (..., n), "s": scales fp32
-(..., n // 128)}`` — both flat, so PartitionSpecs of the bucket apply to
-both (bucket strides are LANE multiples, hence ``n // 128`` divides evenly
-across shard-local layouts).
+A quantized payload is a dict ``{"q": codes (..., rows, 128), "s": scales
+fp32 (..., rows)}``: the codes keep the bucket's storage shape, and the
+scales are that shape's leading dims, so the bucket's PartitionSpec minus
+its lane entry applies to them (bucket strides are LANE multiples, hence
+whole rows on every shard of a shard-local layout).
 
 **Determinism discipline** (the ``exchange_ok`` splitmix32 discipline): the
 stochastic-rounding noise is a pure integer hash keyed on (dispatch step,
@@ -43,6 +44,7 @@ from typing import Any, Dict, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 __all__ = [
     "WIRE_DTYPES",
@@ -51,7 +53,7 @@ __all__ = [
     "wire_uniform",
     "encode_wire",
     "decode_wire",
-    "dequant_flat",
+    "dequant_tiles",
     "zero_payload_like",
     "payload_spec",
     "wire_itemsize",
@@ -146,14 +148,15 @@ def wire_uniform(keys: jnp.ndarray, n: int, base_index=0) -> jnp.ndarray:
 def encode_wire(x: jnp.ndarray, wire_dtype: str, *, keys=None,
                 base_index: int = 0, lane: int = LANE
                 ) -> Union[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Encode one (LANE-multiple) flat bucket ``(..., n)`` for the wire.
+    """Encode one bucket, stored as ``(..., rows, lane)``, for the wire.
 
     fp32 returns ``x`` unchanged; bf16 a plain downcast. int8/fp8 return the
-    ``{"q", "s"}`` payload dict with one fp32 scale ``amax / maxcode`` per
-    ``(row, lane)`` tile. int8 uses unbiased stochastic rounding
-    ``floor(y + u)`` with ``u`` from ``wire_uniform(keys, n, base_index)``
-    (``keys`` from ``wire_key`` — required); fp8 rounds deterministically
-    (cast RTNE), no keys needed. The exact fp32 op sequence here is the
+    ``{"q", "s"}`` payload dict: codes of ``x``'s shape and one fp32 scale
+    ``amax / maxcode`` per ``(row, lane)`` tile, shaped ``(..., rows)``.
+    int8 uses unbiased stochastic rounding ``floor(y + u)`` with ``u`` from
+    ``wire_uniform(keys, n, base_index)`` (``keys`` from ``wire_key`` —
+    required; ``n = rows * lane``, elements indexed row-major); fp8 rounds
+    deterministically (cast RTNE), no keys needed. The exact fp32 op sequence here is the
     bit-exactness contract shared by the shard_map engines and the
     ``core.simulate`` oracle."""
     if wire_dtype == "fp32":
@@ -163,12 +166,13 @@ def encode_wire(x: jnp.ndarray, wire_dtype: str, *, keys=None,
     if wire_dtype not in ("int8", "fp8"):
         raise ValueError(
             f"unknown wire dtype {wire_dtype!r}; options {WIRE_DTYPES}")
-    lead = x.shape[:-1]
-    n = int(x.shape[-1])
-    if n % lane:
+    if x.ndim < 2 or x.shape[-1] != lane:
         raise ValueError(
-            f"quantized wire needs a lane-multiple bucket, got n={n}")
-    xf = x.reshape(lead + (n // lane, lane)).astype(jnp.float32)
+            f"quantized wire needs a lane-multiple bucket stored as "
+            f"(..., rows, {lane}), got {x.shape}")
+    lead, rows = x.shape[:-2], int(x.shape[-2])
+    n = rows * lane
+    xf = x.astype(jnp.float32)
     maxcode = _INT8_MAX if wire_dtype == "int8" else _FP8_MAX
     amax = jnp.max(jnp.abs(xf), axis=-1)
     scale = amax / jnp.float32(maxcode)
@@ -180,40 +184,35 @@ def encode_wire(x: jnp.ndarray, wire_dtype: str, *, keys=None,
                              "for its stochastic rounding")
         u = wire_uniform(jnp.broadcast_to(jnp.asarray(keys, jnp.uint32),
                                           lead), n, base_index)
-        q = jnp.clip(jnp.floor(y + u.reshape(lead + (n // lane, lane))),
+        q = jnp.clip(jnp.floor(y + u.reshape(lead + (rows, lane))),
                      -_INT8_MAX, _INT8_MAX).astype(jnp.int8)
     else:
         # clamp before the cast: e4m3fn has no inf, and a scaled value that
         # rounds past the max finite (448) would encode as nan
         y = jnp.clip(y, -_FP8_MAX, _FP8_MAX)
         q = y.astype(jnp.float8_e4m3fn)
-    return {"q": q.reshape(lead + (n,)), "s": scale}
+    return {"q": q, "s": scale}
 
 
-def dequant_flat(q: jnp.ndarray, s: jnp.ndarray, lane: int = LANE
-                 ) -> jnp.ndarray:
-    """Decode flat codes ``(..., n)`` with per-tile scales ``(..., n//lane)``
-    to fp32: ``codes.astype(f32) * scale`` per tile — the SAME op the Pallas
-    kernels run with the scale as a (rows, 1) column stream, so jnp decode
-    and in-kernel decode are bit-identical."""
-    lead = q.shape[:-1]
-    n = int(q.shape[-1])
-    qf = q.reshape(lead + (n // lane, lane)).astype(jnp.float32)
-    return (qf * s[..., None]).reshape(lead + (n,))
+def dequant_tiles(q: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """Decode codes ``(..., rows, lane)`` with per-tile scales ``(...,
+    rows)`` to fp32: ``codes.astype(f32) * scale`` per tile — the SAME op
+    the Pallas kernels run with the scale as a (rows, 1) column stream, so
+    jnp decode and in-kernel decode are bit-identical."""
+    return q.astype(jnp.float32) * s[..., None]
 
 
 def decode_wire(payload) -> jnp.ndarray:
     """Payload -> mix operand: quantized dicts dequantize to fp32; raw
     fp32/bf16 payloads pass through (the mix casts to fp32 itself)."""
     if isinstance(payload, dict):
-        return dequant_flat(payload["q"], payload["s"])
+        return dequant_tiles(payload["q"], payload["s"])
     return payload
 
 
 # --------------------------------------------------------- payload plumbing
 
-def zero_payload_like(bucket: jnp.ndarray, wire_dtype: str,
-                      lane: int = LANE):
+def zero_payload_like(bucket: jnp.ndarray, wire_dtype: str):
     """The ring-slot filler for an unsent bucket (partition sampling) and
     the wire-ring bootstrap: an all-zero payload of the right wire shape.
     Zero codes x zero scales decode to exact zeros, and the slot is only
@@ -223,18 +222,16 @@ def zero_payload_like(bucket: jnp.ndarray, wire_dtype: str,
     if wire_dtype == "bf16":
         return jnp.zeros(bucket.shape, jnp.bfloat16)
     qdt = jnp.int8 if wire_dtype == "int8" else jnp.float8_e4m3fn
-    lead = bucket.shape[:-1]
-    n = int(bucket.shape[-1])
     return {"q": jnp.zeros(bucket.shape, qdt),
-            "s": jnp.zeros(lead + (n // lane,), jnp.float32)}
+            "s": jnp.zeros(bucket.shape[:-1], jnp.float32)}
 
 
 def payload_spec(bucket_spec, wire_dtype: str):
-    """PartitionSpec tree of one bucket's wire payload: codes AND scales are
-    flat with the bucket's sharding (strides are lane multiples, so the
-    scale dim divides evenly across shard-local layouts)."""
+    """PartitionSpec tree of one bucket's wire payload: the codes take the
+    bucket's ``(dp, rows, lane)`` spec and the ``(dp, rows)`` scales the
+    same spec without its (unsharded) lane entry."""
     if wire_dtype in ("int8", "fp8"):
-        return {"q": bucket_spec, "s": bucket_spec}
+        return {"q": bucket_spec, "s": P(*tuple(bucket_spec)[:-1])}
     return bucket_spec
 
 

@@ -127,27 +127,27 @@ def _lars_row_scale(layout, bucket_idx: int, p, g, partner, *, alpha: float,
     use_partner = partner is not None and not (
         isinstance(alpha, (int, float)) and alpha == 0.0)
     lane = layout.lane
-    n = int(p.shape[-1])
+    rows = int(p.shape[-2])
     slots = sorted((s for s in layout.slots if s.bucket == bucket_idx),
                    key=lambda s: s.offset)
-    rows = n // lane
     row_map = np.full((rows,), len(slots), np.int32)  # default: padding
     for k, s in enumerate(slots):
         row_map[s.offset // lane: -(-(s.offset + s.size) // lane)] = k
     row_map = jnp.asarray(row_map)
 
+    def piece(x, s):
+        """The slot's elements: its whole rows, then the slot's length."""
+        r0, r1 = s.offset // lane, -(-(s.offset + s.size) // lane)
+        return x[r0:r1].reshape(-1)[:s.size].astype(jnp.float32)
+
     def one_replica(pr, gr, br):
         trusts = []
         for s in slots:
-            pf = jax.lax.slice_in_dim(pr, s.offset, s.offset + s.size
-                                      ).astype(jnp.float32)
+            pf = piece(pr, s)
             if br is not None:
-                bf = jax.lax.slice_in_dim(br, s.offset, s.offset + s.size
-                                          ).astype(jnp.float32)
-                pf = (pf * (1.0 - alpha) + bf * alpha
+                pf = (pf * (1.0 - alpha) + piece(br, s) * alpha
                       ).astype(pr.dtype).astype(jnp.float32)
-            gf = jax.lax.slice_in_dim(gr, s.offset, s.offset + s.size
-                                      ).astype(jnp.float32)
+            gf = piece(gr, s)
             if weight_decay:
                 gf = gf + weight_decay * pf
             wn = jnp.linalg.norm(pf.reshape(-1))
@@ -158,10 +158,10 @@ def _lars_row_scale(layout, bucket_idx: int, p, g, partner, *, alpha: float,
         table = jnp.stack(trusts + [jnp.float32(1.0)])
         return table[row_map]
 
-    lead = p.shape[:-1]
-    pf2, gf2 = p.reshape((-1, n)), g.reshape((-1, n))
+    lead = p.shape[:-2]
+    pf2, gf2 = p.reshape((-1, rows, lane)), g.reshape((-1, rows, lane))
     if use_partner:
-        bf2 = partner.reshape((-1, n))
+        bf2 = partner.reshape((-1, rows, lane))
         scale = jax.vmap(one_replica)(pf2, gf2, bf2)
     else:
         scale = jax.vmap(lambda a, b: one_replica(a, b, None))(pf2, gf2)
@@ -232,7 +232,7 @@ def lars(schedule: Schedule | float, momentum: float = 0.9,
         a per-(row, 128)-tile scale; then the single-sweep kernel applies
         mix + momentum + trust-scaled step.  Unlike the tree-level packed
         update there is NO per-step re-pack concatenate."""
-        from repro.kernels import dequant_flat, fused_lars_bucket
+        from repro.kernels import dequant_tiles, fused_lars_bucket
         if layout is None:
             raise ValueError("lars.fused_update needs the BucketLayout for "
                              "its per-layer norm prepass")
@@ -240,7 +240,7 @@ def lars(schedule: Schedule | float, momentum: float = 0.9,
             # quantized wire payload: pre-decode once — the norm prepass
             # reads the mixed params, so the decode cannot stay in-kernel
             # here; dequant-then-mix is bit-identical to in-kernel decode
-            partner = dequant_flat(partner["q"], partner["s"])
+            partner = dequant_tiles(partner["q"], partner["s"])
         (mom,) = moments
         scale = _lars_row_scale(
             layout, bucket_idx, p, g, partner, alpha=alpha,

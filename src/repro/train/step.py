@@ -230,7 +230,7 @@ def make_train_step_bundle(
     ``PackedParams.unpack()`` view (lars does).  Distributions that shard
     inside a replica (fsdp's FSDP+TP, replica-mode tensor parallelism) get a
     SHARD-LOCAL layout: each (data, model) position packs its own shard
-    bytes into the buckets, the bucket flat dim shards over
+    bytes into the buckets, the bucket rows shard over
     ``dist.shard_axes``, and gossip still ppermutes over the replica axes
     only — the hierarchical GossipGraD regime (pods gossip, each pod holds
     one sharded copy).
@@ -471,7 +471,7 @@ def _build_packed_layout(dist: Distribution, param_shapes: PyTree,
     smoke) get the flat PR-1 layout; distributions that do (fsdp's FSDP+TP,
     replica-mode tensor parallelism) get a SHARD-LOCAL layout keyed by
     (leaf, shard_index) — each in-replica mesh position packs its own shard
-    bytes, and the bucket flat dim shards over ``dist.shard_axes``. A spec
+    bytes, and the bucket rows shard over ``dist.shard_axes``. A spec
     that uses a replica axis beyond the leading dim is still rejected (it
     would alias replica bytes into the shard partition)."""
     from jax.sharding import PartitionSpec

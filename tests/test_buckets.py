@@ -25,16 +25,49 @@ def _odd_tree(dtype, lead=()):
     return {"w1": mk(5, 3), "w2": mk(130,), "w3": mk(2, 7, 11), "b": mk(1,)}
 
 
+def _layout(tree, lead, kind):
+    """A flat layout, or a shard-local one over a (data 2, model 2) replica
+    (``w3`` sharded on its first dim, the rest chunked over both axes)."""
+    if kind == "flat":
+        return build_layout(tree, skip_leading=len(lead))
+    from jax.sharding import PartitionSpec as P
+    return build_layout(
+        tree, skip_leading=len(lead), shard_axes=("data", "model"),
+        shard_axis_sizes=(2, 2),
+        shard_specs={"w1": None, "w2": None, "w3": P("data"), "b": None})
+
+
+@pytest.mark.parametrize("kind", ["flat", "shard_local"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("lead", [(), (4,)])
-def test_pack_unpack_roundtrip(dtype, lead):
+def test_pack_unpack_roundtrip(dtype, lead, kind):
     tree = _odd_tree(dtype, lead)
-    layout = build_layout(tree, skip_leading=len(lead))
+    layout = _layout(tree, lead, kind)
     packed = PackedParams.pack(tree, layout)
+    # every bucket is stored as the kernels' (rows, 128) view
+    for i, (b, n) in enumerate(zip(packed.buckets, layout.bucket_sizes)):
+        assert b.shape == lead + layout.bucket_shape(i) == lead + (
+            n // LANE, LANE)
     out = packed.unpack()
     for k in tree:
         np.testing.assert_array_equal(
             np.asarray(out[k], np.float32), np.asarray(tree[k], np.float32))
+
+
+@pytest.mark.parametrize("kind", ["flat", "shard_local"])
+def test_bucket_sizes_count_elements(kind):
+    """``bucket_sizes`` stays each bucket's element count, the count the
+    update kernel's byte model reads: the storage's rows times the lane."""
+    tree = _odd_tree(jnp.float32, (4,))
+    layout = _layout(tree, (4,), kind)
+    if kind == "flat":
+        # w1 15 -> 128, w2 130 -> 256, w3 154 -> 256, b 1 -> 128
+        assert layout.bucket_sizes == (768,)
+    else:
+        assert layout.bucket_sizes == tuple(4 * s for s in layout.strides)
+    packed = PackedParams.pack(tree, layout)
+    assert [int(np.prod(b.shape[1:])) for b in packed.buckets] == list(
+        layout.bucket_sizes)
 
 
 def test_layout_invariants():
@@ -58,18 +91,24 @@ def test_layout_balances_buckets():
     assert layout.bucket_sizes[0] == layout.bucket_sizes[1]
 
 
-def test_packed_params_is_elementwise_pytree():
+@pytest.mark.parametrize("kind", ["flat", "shard_local"])
+def test_packed_params_is_elementwise_pytree(kind):
     tree = _odd_tree(jnp.float32)
-    packed = PackedParams.pack(tree)
+    packed = PackedParams.pack(tree, _layout(tree, (), kind))
     doubled = jax.tree.map(lambda x: x * 2.0, packed)
     assert isinstance(doubled, PackedParams)
     out = doubled.unpack()
     np.testing.assert_allclose(np.asarray(out["w2"]),
                                2.0 * np.asarray(tree["w2"]), rtol=1e-6)
-    # gradients w.r.t. the buckets arrive packed — no per-step concat
+    # gradients w.r.t. the buckets arrive packed, in the buckets' storage
+    # shape, and equal to the leaves' gradients packed — no per-step concat
     g = jax.grad(lambda p: sum(jnp.sum(l.astype(jnp.float32) ** 2)
                                for l in jax.tree.leaves(p.unpack())))(packed)
     assert isinstance(g, PackedParams)
+    assert [b.shape for b in g.buckets] == [b.shape for b in packed.buckets]
+    for a, b in zip(g.buckets, jax.tree.map(lambda x: 2.0 * x,
+                                            packed).buckets):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     jaxpr = str(jax.make_jaxpr(
         lambda p: jax.tree.map(lambda x: x * 0.5, p))(packed))
     assert "concatenate" not in jaxpr
@@ -80,7 +119,7 @@ def test_packed_param_specs_structure():
     layout = build_layout(_odd_tree(jnp.float32, (4,)), skip_leading=1)
     specs = packed_param_specs(layout, ("data",))
     assert isinstance(specs, PackedParams)
-    assert all(s == P("data", None) for s in specs.buckets)
+    assert all(s == P("data", None, None) for s in specs.buckets)
 
 
 def test_checkpoint_roundtrip_and_cross_format(tmp_path):
@@ -107,6 +146,56 @@ def test_checkpoint_roundtrip_and_cross_format(tmp_path):
     assert isinstance(rest3["params"], PackedParams)
     np.testing.assert_array_equal(np.asarray(rest3["params"].unpack()["w3"]),
                                   np.asarray(tree["w3"]))
+
+
+def test_checkpoint_with_flat_buckets_resumes_bit_identically(tmp_path):
+    """A checkpoint that holds bucket-shaped state flat, ``(dp, rows *
+    128)`` — the int8 wire ring's payload codes, as written before buckets
+    were stored ``(dp, rows, 128)`` — restores by a reshape, and the run
+    resumed from it is bit-identical to the one that never stopped."""
+    import json
+
+    from repro.checkpoint import restore_state, save_state
+    from repro.core.async_gossip import init_wire_inbox_ring
+    from repro.core.simulate import gossip_mix_sim_quantized_k
+    from repro.kernels.quantize import WireFormat
+
+    p, k = 4, 2
+    wire = WireFormat(dtype="int8", seed=5)
+    tree = _odd_tree(jnp.float32, (p,))
+    packed = PackedParams.pack(tree, build_layout(tree, skip_leading=1))
+    recv = jnp.asarray([1, 2, 3, 0])
+    step = jax.jit(lambda s: (lambda b, r: {
+        "params": PackedParams(b, packed.layout), "inbox": r})(
+        *gossip_mix_sim_quantized_k(list(s["params"].buckets), s["inbox"],
+                                    recv, wire=wire)))
+    state = {"params": packed,
+             "inbox": init_wire_inbox_ring(packed, k, p, wire)}
+    for _ in range(k + 1):            # real payloads in every slot
+        state = step(state)
+    d = tmp_path / "ck"
+    save_state(str(d), state, step=k + 1)
+    # rewrite the checkpoint as the flat-bucket store wrote it
+    man = json.loads((d / "manifest.json").read_text())
+    arrays = dict(np.load(d / "arrays.npz"))
+    flat = 0
+    for i, key in enumerate(man["keys"]):
+        a = arrays[f"a{i}"]
+        if a.ndim == 3 and a.shape[-1] == LANE:
+            arrays[f"a{i}"] = a.reshape(a.shape[0], -1)
+            man["shapes"][key] = list(arrays[f"a{i}"].shape)
+            flat += 1
+    assert flat == k                  # one code array per slot
+    np.savez(d / "arrays.npz", **arrays)
+    (d / "manifest.json").write_text(json.dumps(man))
+
+    restored, _ = restore_state(str(d), state)
+    restored = jax.tree.map(jnp.asarray, restored)
+    for _ in range(3):
+        state, restored = step(state), step(restored)
+        for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
+            assert a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_packed_training_matches_leaf_training():

@@ -124,6 +124,10 @@ def test_fused_bucket_matches_unfused_composition(opt_name, opt, dtype, alpha):
         for impl in fus:
             fp[impl], fst[impl] = fus[impl](params=fp[impl], grads=gp,
                                             state=fst[impl], partner=partner)
+        # both impls keep the buckets' (rows, 128) storage shape
+        for impl in fus:
+            assert [b.shape for b in fp[impl].buckets] == [
+                layout.bucket_shape(i) for i in range(layout.num_buckets)]
         # jnp impl vs pallas-interpret impl: identical programs, bit-equal
         for a, b in zip(fp["jnp"].buckets, fp["pallas"].buckets):
             np.testing.assert_array_equal(np.asarray(a, np.float32),
@@ -490,7 +494,8 @@ for opt_name, opt in OPTS:
             new_slot = PackedParams([b[recv_from] for b in rp.buckets],
                                     layout)
             mixed = PackedParams(
-                [((1.0 - a[:, None]) * b + a[:, None] * ib).astype(b.dtype)
+                [((1.0 - a[:, None, None]) * b
+                  + a[:, None, None] * ib).astype(b.dtype)
                  for b, ib in zip(rp.buckets, slots[0].buckets)], layout)
             new_p, new_st = opt.update(mixed, grads, rst)
             new_ring = {"slots": tuple(slots[1:]) + (new_slot,),

@@ -122,7 +122,7 @@ def test_no_shard_axes_reduces_to_flat_layout():
 def test_hier_packed_param_specs():
     layout = _hier_layout(_tree())
     specs = packed_param_specs(layout, ("pod",))
-    assert all(s == P("pod", ("data", "model")) for s in specs.buckets)
+    assert all(s == P("pod", ("data", "model"), None) for s in specs.buckets)
     # replica axes may not double as shard axes
     with pytest.raises(ValueError, match="shard"):
         packed_param_specs(layout, ("data",))

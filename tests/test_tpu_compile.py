@@ -11,6 +11,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import importlib.util
+import math
 import os
 import pathlib
 import re
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels.ops as kernel_ops
 from repro.configs import get_config
 from repro.core.buckets import DEFAULT_BUCKET_BYTES
 from repro.kernels.flash_attention import flash_attention
@@ -83,7 +85,7 @@ def _update_kernel_pattern():
                                      (jnp.float32, F32_N)])
 @pytest.mark.parametrize("alpha", [0.0, 0.5, "traced"])
 def test_fused_sgd_compiles(one_chip, dtype, n, alpha):
-    s = lambda dt: _shape(one_chip, (1, n), dt)
+    s = lambda dt: _shape(one_chip, (1, n // LANE, LANE), dt)
     args = [s(dtype), s(dtype), s(dtype), s(dtype)]
     if alpha == "traced":
         args.append(_shape(one_chip, (), jnp.float32))
@@ -98,7 +100,7 @@ def test_fused_sgd_compiles(one_chip, dtype, n, alpha):
 @pytest.mark.parametrize("dtype,n", [(jnp.bfloat16, BF16_N),
                                      (jnp.float32, F32_N)])
 def test_fused_adamw_compiles(one_chip, dtype, n):
-    s = lambda dt: _shape(one_chip, (1, n), dt)
+    s = lambda dt: _shape(one_chip, (1, n // LANE, LANE), dt)
     fn = lambda p, g, b, m, v: fused_adamw_1d(p, g, b, m, v, lr=0.1, c1=0.1,
                                               c2=0.1, alpha=0.5, donate=True)
     args = [s(dtype), s(dtype), s(dtype), s(jnp.float32), s(jnp.float32)]
@@ -125,9 +127,10 @@ def test_gossip_mix_q2d_compiles(one_chip, code_dtype):
 
 @pytest.mark.parametrize("code_dtype", [jnp.int8, FP8])
 def test_fused_sgd_quantized_partner_compiles(one_chip, code_dtype):
-    s = lambda dt: _shape(one_chip, (1, BF16_N), dt)
+    s = lambda dt: _shape(one_chip, (1, BF16_N // LANE, LANE), dt)
     args = [s(jnp.bfloat16), s(jnp.bfloat16), s(code_dtype),
-            _shape(one_chip, (BF16_N // LANE,), jnp.float32), s(jnp.bfloat16)]
+            _shape(one_chip, (1, BF16_N // LANE), jnp.float32),
+            s(jnp.bfloat16)]
     fn = lambda p, g, q, sc, m: fused_sgd_1d(p, g, q, m, lr=0.1, alpha=0.5,
                                              partner_scales=sc, donate=True)
     assert "tpu_custom_call" in _compile_text(fn, args, donate=(0, 4))
@@ -136,7 +139,8 @@ def test_fused_sgd_quantized_partner_compiles(one_chip, code_dtype):
 def test_fp8_wire_encode_compiles(one_chip):
     """v5e has no fp8 hardware; the e4m3 encode must still compile."""
     fn = lambda x: encode_wire(x, "fp8")
-    _compile_text(fn, [_shape(one_chip, (1, BF16_N), jnp.bfloat16)])
+    _compile_text(fn, [_shape(one_chip, (1, BF16_N // LANE, LANE),
+                              jnp.bfloat16)])
 
 
 def test_kernels_carry_their_names(one_chip):
@@ -234,12 +238,12 @@ def test_grouped_matmul_fwd_bwd_compiles(one_chip):
         assert re.search(rf"\[(12288,\d+|{E},\d+,\d+)\]", c), c
 
 
-def test_step_takes_flash_per_replica_on_four_chips(topo):
-    """The gossip step of a two-layer qwen3 at data=4 on a described
-    v5e:2x2: every attention site takes the kernels, and no all-gather
-    feeds them (each replica's call runs on its own chip)."""
+def _packed_gossip_step(topo, dp, monkeypatch=None):
+    """(bundle, state shapes, compiled) of the packed gossip step of a
+    two-layer qwen3 on ``dp`` chips of a described v5e:2x2, one replica
+    per chip."""
     cfg = reduced(get_config("qwen3-0.6b"), d_model=512)
-    mesh = make_mesh((4, 1), ("data", "model"), devices=topo.devices[:4])
+    mesh = make_mesh((dp, 1), ("data", "model"), devices=topo.devices[:dp])
     dist = make_distribution(mesh, cfg.dist_mode)
     opt = sgd(0.01, momentum=0.9)
     shapes, axes, batch = train_input_specs(cfg, dist, 256, 4, opt)
@@ -253,9 +257,123 @@ def test_step_takes_flash_per_replica_on_four_chips(topo):
     place = lambda tree, shard: jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         tree, shard)
-    text = bundle.jitted(0).lower(
+    if monkeypatch is not None:     # the fused update on its Pallas path
+        monkeypatch.setattr(kernel_ops, "interpret", lambda: False)
+    compiled = bundle.jitted(0).lower(
         place(state, bundle.state_shardings),
-        place(batch, bundle.batch_shardings)).compile().as_text()
+        place(batch, bundle.batch_shardings)).compile()
+    return bundle, state, compiled
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
+# ops that hand a whole buffer on unchanged: a view, a move between memory
+# spaces, a slice of it or a tuple element
+_PASS = {"bitcast", "copy-start", "copy-done", "get-tuple-element",
+         "slice-start", "slice-done"}
+
+
+def _entry(text):
+    """{name: (opcode, operand names, line)} of the ENTRY computation, and
+    the ROOT's name."""
+    body = text[text.index("\nENTRY"):].split("\n", 1)[1]
+    body = body[:body.index("\n}")]
+    ins, root = {}, None
+    for line in body.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, _, op, rest = m.groups()
+        depth, args = 1, ""
+        for ch in rest:
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+            args += ch
+        if op == "custom-call" and 'custom_call_target="ConcatBitcast"' in line:
+            op = "bitcast"          # pieces of one buffer laid end to end
+        ins[name] = (op, re.findall(r"%([\w.\-]+)", args), line)
+        if line.lstrip().startswith("ROOT"):
+            root = name
+    return ins, root
+
+
+def _elements(line):
+    dims = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def _state_relayouts(text):
+    """Names of the ENTRY's reduces, copies and copy/reduce fusions that
+    read a parameter or momentum bucket of the state, or write one into the
+    step's result."""
+    ins, root = _entry(text)
+    state = {n for n, (op, _, line) in ins.items() if op == "parameter"
+             and re.search(r"op_name=\"state\[\\'(params|opt\\'\]\[\\'mom)\\'\]",
+                           line)}
+    sizes = {_elements(ins[n][2]) for n in state}
+    grown = True
+    while grown:                    # what the state's buffers pass through
+        more = {n for n, (op, args, _) in ins.items()
+                if op in _PASS and n not in state and set(args) & state}
+        grown = bool(more)
+        state |= more
+    out, todo = set(), [n for n in ins[root][1]
+                        if _elements(ins[n][2]) in sizes]
+    while todo:                     # what the result's buffers come from
+        n = todo.pop()
+        if n in out or n not in ins:
+            continue
+        out.add(n)
+        if ins[n][0] in _PASS:
+            todo.extend(ins[n][1])
+    relayout = lambda n, op: op in ("reduce", "copy") or (
+        op == "fusion" and re.match(r"(copy|\w*reduce)\w*_fusion", n))
+    return sorted(n for n, (op, args, _) in ins.items()
+                  if relayout(n, op) and (set(args) & state or n in out))
+
+
+@pytest.mark.parametrize("dp", [1, 4])
+def test_packed_step_keeps_buckets_in_kernel_view(topo, dp, monkeypatch):
+    """The parameter and momentum buckets are stored in the fused update's
+    (rows, 128) view: the compiled step hands them to the kernel and takes
+    them back by bitcasts alone, with no reduce or copy of a bucket, and
+    the state's arguments hold its bytes with no tile padding."""
+    bundle, state, compiled = _packed_gossip_step(topo, dp, monkeypatch)
+    text = compiled.as_text()
+    assert any(c.startswith("%fused_update") for c in _custom_calls(text))
+    assert all(b.shape[-2:] == (n // LANE, LANE) for b, n in zip(
+        state["params"].buckets, bundle.layout.bucket_sizes))
+    assert _state_relayouts(text) == []
+    data = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    batch = 4 * 257 * 4             # the int32 (dp, b, S + 1) tokens
+    args = compiled.memory_analysis().argument_size_in_bytes
+    # the scalars' and the batch's tiles add a few KiB; a bucket padded to
+    # its tiles would add its own size
+    assert 0 <= args - (data + batch) // dp < 16 << 10, (args, data)
+
+
+def test_state_relayouts_counts_the_flat_store(one_chip):
+    """The counter above finds the relayouts of a bucket stored flat, as
+    ``(1, n)``: XLA:TPU tiles that with the size-1 axis as sublanes, so the
+    kernel's (rows, 128) view is a reduce in and a copy out."""
+    n = 64 * LANE
+    s = _shape(one_chip, (1, n), jnp.bfloat16)
+
+    def step(state, g):
+        p, m = fused_sgd_1d(state["params"], g, None, state["opt"],
+                            lr=0.1, alpha=0.0, donate=True)
+        return {"params": p, "opt": m}
+
+    text = _compile_text(step, [{"params": s, "opt": s}, s], donate=(0,))
+    assert len(_state_relayouts(text)) >= 2
+
+
+def test_step_takes_flash_per_replica_on_four_chips(topo):
+    """The gossip step of a two-layer qwen3 at data=4 on a described
+    v5e:2x2: every attention site takes the kernels, and no all-gather
+    feeds them (each replica's call runs on its own chip)."""
+    bundle, _, compiled = _packed_gossip_step(topo, 4)
+    text = compiled.as_text()
     assert bundle.attn_paths == {"flash": 1, "dense": 0}
     assert _flash_kernels(text) == {"flash_attention", "flash_attention_dkv",
                                     "flash_attention_dq"}

@@ -37,7 +37,7 @@ from repro.core import (build_layout, build_schedule, build_subset_schedule,
 from repro.core.buckets import PackedParams
 from repro.core.topology import BucketSubsetSchedule
 from repro.kernels.quantize import (LANE, WIRE_DTYPES, WireFormat,
-                                    decode_wire, dequant_flat, encode_wire,
+                                    decode_wire, dequant_tiles, encode_wire,
                                     payload_spec, wire_itemsize, wire_key,
                                     wire_uniform, zero_payload_like)
 from repro.optim import sgd
@@ -108,12 +108,12 @@ def test_int8_roundtrip_bounded_and_unbiased():
     stochastic rounding is unbiased — averaging the decode over many
     dispatch steps converges on the input."""
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(4, 256)), jnp.float32) * 3.0
+    x = jnp.asarray(rng.normal(size=(4, 2, LANE)), jnp.float32) * 3.0
     pay = encode_wire(x, "int8", keys=wire_key(0, jnp.arange(4), 0, 0))
-    assert pay["q"].shape == (4, 256) and pay["q"].dtype == jnp.int8
+    assert pay["q"].shape == (4, 2, LANE) and pay["q"].dtype == jnp.int8
     assert pay["s"].shape == (4, 2) and pay["s"].dtype == jnp.float32
     dec = np.asarray(decode_wire(pay))
-    step = np.repeat(np.asarray(pay["s"]), LANE, axis=1)
+    step = np.repeat(np.asarray(pay["s"])[..., None], LANE, axis=-1)
     assert np.all(np.abs(dec - np.asarray(x)) <= step + 1e-7)
     acc = np.zeros_like(dec)
     n_draws = 200
@@ -129,17 +129,17 @@ def test_fp8_encode_finite_and_bounded():
     overflow would round to nan) and lands within the format's ~6%
     relative-error envelope per tile."""
     rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.normal(size=(2, 128)), jnp.float32) * 1e4
-    x = x.at[0, 0].set(3e4)  # the tile amax itself
+    x = jnp.asarray(rng.normal(size=(2, 1, LANE)), jnp.float32) * 1e4
+    x = x.at[0, 0, 0].set(3e4)  # the tile amax itself
     pay = encode_wire(x, "fp8")
     assert pay["q"].dtype == jnp.float8_e4m3fn
     dec = np.asarray(decode_wire(pay))
     assert np.isfinite(dec).all()
     denom = np.maximum(np.abs(np.asarray(x)), 1e-30)
-    scale = np.repeat(np.asarray(pay["s"]), LANE, axis=1)
+    scale = np.repeat(np.asarray(pay["s"])[..., None], LANE, axis=-1)
     assert np.all(np.abs(dec - np.asarray(x)) <= 0.07 * denom + scale)
     # all-zero tiles encode scale 0 and decode to exact zeros
-    z = encode_wire(jnp.zeros((1, 128)), "fp8")
+    z = encode_wire(jnp.zeros((1, 1, LANE)), "fp8")
     assert np.asarray(z["s"])[0, 0] == 0.0
     np.testing.assert_array_equal(np.asarray(decode_wire(z)), 0.0)
 
@@ -162,11 +162,11 @@ def test_shard_local_encode_matches_global():
     offsets reproduces the full-bucket encode bit-for-bit (amax tiles never
     straddle shards — strides are LANE multiples)."""
     rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=(4, 256)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(4, 2, LANE)), jnp.float32)
     keys = wire_key(9, jnp.arange(4), 1, seed=2)
     full = encode_wire(x, "int8", keys=keys)
-    lo = encode_wire(x[:, :128], "int8", keys=keys, base_index=0)
-    hi = encode_wire(x[:, 128:], "int8", keys=keys, base_index=128)
+    lo = encode_wire(x[:, :1], "int8", keys=keys, base_index=0)
+    hi = encode_wire(x[:, 1:], "int8", keys=keys, base_index=128)
     bits_eq(np.concatenate([np.asarray(lo["q"]), np.asarray(hi["q"])], 1),
             full["q"])
     bits_eq(np.concatenate([np.asarray(lo["s"]), np.asarray(hi["s"])], 1),
@@ -174,16 +174,16 @@ def test_shard_local_encode_matches_global():
 
 
 def test_payload_plumbing_and_itemsize():
-    b = jnp.ones((2, 256), jnp.float32)
+    b = jnp.ones((2, 2, LANE), jnp.float32)
     for dt in ("int8", "fp8"):
         z = zero_payload_like(b, dt)
-        assert z["q"].shape == (2, 256) and z["s"].shape == (2, 2)
+        assert z["q"].shape == (2, 2, LANE) and z["s"].shape == (2, 2)
         np.testing.assert_array_equal(np.asarray(decode_wire(z)), 0.0)
     assert zero_payload_like(b, "bf16").dtype == jnp.bfloat16
     assert zero_payload_like(b, "fp32").dtype == jnp.float32
     from jax.sharding import PartitionSpec as P
-    spec = P("data", None)
-    assert payload_spec(spec, "int8") == {"q": spec, "s": spec}
+    spec = P("data", None, None)
+    assert payload_spec(spec, "int8") == {"q": spec, "s": P("data", None)}
     assert payload_spec(spec, "fp32") == spec
     assert wire_itemsize("fp32", np.float32) == 4
     assert wire_itemsize("fp32", jnp.bfloat16) == 2
@@ -192,7 +192,7 @@ def test_payload_plumbing_and_itemsize():
     assert wire_itemsize("fp8", np.float32) == 1
     # decode path used by the kernels' jnp twin
     pay = encode_wire(b * 3, "int8", keys=wire_key(0, jnp.arange(2), 0))
-    bits_eq(dequant_flat(pay["q"], pay["s"]), decode_wire(pay))
+    bits_eq(dequant_tiles(pay["q"], pay["s"]), decode_wire(pay))
 
 
 # ---------------------------------------------------- bucket-subset schedule
